@@ -104,9 +104,13 @@ def build_conv_trace(emitted: EmittedConv) -> KernelTrace:
 
     Mirrors the kernel's ``pl.when`` structure exactly: per step, the
     retire-wait for the delta prefetched one step earlier, the window
-    shift/splice, the next step's prefetch start, then the compute read
-    and output-block write.  Every region comes from evaluating the same
+    shift/splice (its static right- or left-moving branch), the next
+    step's prefetch start, then the per-tap compute reads and the
+    output-block write.  Every region comes from evaluating the same
     geometry helpers the kernel traces with, on concrete indices.
+    Regions are logical ``(c, h, w)`` boxes; the kernel stores the same
+    boxes as ``(h, w, pixel)`` buffers, a pixel's channels padded to
+    lane tiles (``conv2d_offload.pixel_shape``).
     """
     spec, t = emitted.spec, emitted.t_run
     return _conv_trace(spec, t, emitted.order,
@@ -147,8 +151,8 @@ def _conv_trace(spec: ConvSpec, t: int, order: str, *, name: str,
 
     steps: list[StepTrace] = []
     events: list[access.Event] = []
-    row_full = access.box_region("row_buf", (0, c), (0, max(1, sh)),
-                                 (0, t_in))
+    row_full = access.box_region("row_buf", (0, c),
+                                 (0, max(1, min(sh, hk))), (0, t_in))
     col_full = access.box_region("col_buf", (0, c), (0, hk), (0, nw))
     for k, (i, jt_raw) in enumerate(seq):
         jt = eff_tile(i, jt_raw, tiles, order == "zigzag")
@@ -166,16 +170,18 @@ def _conv_trace(spec: ConvSpec, t: int, order: str, *, name: str,
             events.append(access.BufWrite(win_box(r0=0, rn=keep), k))
             events.append(access.BufRead(row_full, k))
             events.append(access.BufWrite(win_box(r0=keep, rn=sh), k))
-        else:                                           # CASE_COL
-            right = moving_right(i, order == "zigzag")
+        elif moving_right(i, order == "zigzag"):        # CASE_COL, right
             events.append(access.DmaWait("col", k))
-            events.append(access.BufRead(
-                win_box(c0=nw if right else 0, cn=ov_w), k))
-            events.append(access.BufWrite(
-                win_box(c0=0 if right else nw, cn=ov_w), k))
+            events.append(access.BufRead(win_box(c0=nw, cn=ov_w), k))
+            events.append(access.BufWrite(win_box(c0=0, cn=ov_w), k))
             events.append(access.BufRead(col_full, k))
-            events.append(access.BufWrite(
-                win_box(c0=ov_w if right else 0, cn=nw), k))
+            events.append(access.BufWrite(win_box(c0=ov_w, cn=nw), k))
+        else:                                           # CASE_COL, left
+            events.append(access.DmaWait("col", k))
+            events.append(access.BufRead(win_box(c0=0, cn=ov_w), k))
+            events.append(access.BufWrite(win_box(c0=nw, cn=ov_w), k))
+            events.append(access.BufRead(col_full, k))
+            events.append(access.BufWrite(win_box(c0=0, cn=nw), k))
 
         if k + 1 < len(seq):                            # prefetch next delta
             i_n, jt_raw_n = seq[k + 1]
@@ -192,7 +198,7 @@ def _conv_trace(spec: ConvSpec, t: int, order: str, *, name: str,
 
         out = access.box_region("out", (0, spec.c_out), (i, i + 1),
                                 (jt * t, jt * t + t))
-        events.append(access.BufRead(win_box(), k))     # im2col + dot
+        events.append(access.BufRead(win_box(), k))     # per-tap dots
         events.append(access.BufWrite(out, k))
         steps.append(StepTrace(
             index=k, x_load=load,

@@ -25,7 +25,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import KernelShapeError
+from repro.kernels import KernelShapeError, resolve_interpret
 
 
 def matmul_grid(m: int, n: int, k: int, *, bm: int, bn: int, bk: int,
@@ -94,7 +94,7 @@ def _mm_kernel_rmw(a_ref, b_ref, o_ref, *, k_axis: int, k_tiles: int):
 def block_matmul(a: jax.Array, b: jax.Array, *,
                  bm: int = 128, bn: int = 128, bk: int = 128,
                  order: str = "mnk",
-                 interpret: bool = True) -> jax.Array:
+                 interpret: bool | None = None) -> jax.Array:
     """C = A @ B with planner-chosen tiles and loop order.
 
     ``order`` is outer->inner over the grid axes, e.g. "mnk" iterates k
@@ -120,6 +120,7 @@ def block_matmul(a: jax.Array, b: jax.Array, *,
                                    k_tiles=k_t)
         scratch = []
         out_dtype = jnp.float32     # RMW partials accumulate in f32
+    interpret = resolve_interpret(interpret)
     out = pl.pallas_call(
         kernel,
         grid=grid,

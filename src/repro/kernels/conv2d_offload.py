@@ -7,39 +7,42 @@ Strategy S1, faithfully mapped to the TPU memory hierarchy:
     is constant, so Pallas revisits (never re-fetches) the block: exactly
     "loaded during the first step and never freed until the last step"
     (Def 16).
-  * **I_slice** — the input lives in HBM (the paper's DRAM,
-    ``memory_space=pl.ANY``).  Each grid step DMAs the patch-group window
-    into a VMEM scratch buffer with ``pltpu.make_async_copy`` — action a4.
+  * **I_slice** — the input lives in HBM (the paper's DRAM).  Each grid
+    step DMAs its window (or the part of it not yet resident) into a VMEM
+    scratch buffer with ``pltpu.make_async_copy`` — action a4.
   * **patch groups** — one step computes a row-run of T output columns for
-    *all* C_out channels (Property 1).  T comes from
-    ``core.planner.plan_conv`` (the nb_patches_max analogue under the VMEM
-    budget).  Grid order is zigzag (paper Sec 7.2) or row-by-row.
-  * **W / write-back** — the step's (C_out, 1, T) output block leaves VMEM
-    when the grid moves on — action a3.
+    *all* C_out channels (Property 1).  T comes from the planner (the
+    nb_patches_max analogue under the VMEM budget).  Grid order is zigzag
+    (paper Sec 7.2) or row-by-row.
+  * **W / write-back** — the step's output block leaves VMEM when the grid
+    moves on — action a3.
 
 Two variants share the geometry helpers below (which
 ``repro.analysis.kerncheck`` also evaluates on concrete grid indices to
 derive each kernel's static access trace):
 
-* :func:`conv2d_offload` — the simple seed kernel: every step DMAs its
-  *full* ``(C_in, H_K, t_in)`` window and blocks on the copy.  Correct,
-  but it re-fetches the ``w_k - s_w`` columns (and, across rows, the
-  ``h_k - s_h`` rows) shared with the previous step — traffic the plan's
-  Def-3 ``I_slice`` accounting does *not* charge.
+* :func:`conv2d_offload` — the simple seed kernel on (C, H, W) arrays:
+  every step DMAs its *full* ``(C_in, H_K, t_in)`` window, blocks on the
+  copy, and runs an im2col-in-VMEM plus one MXU dot.  It re-fetches the
+  ``w_k - s_w`` columns (and, across rows, the ``h_k - s_h`` rows) shared
+  with the previous step — traffic the plan's Def-3 ``I_slice``
+  accounting does *not* charge.  It runs in interpret mode only: its
+  (C_out, 1, T) output block and in-kernel reshape do not compile for
+  the chip.
 * :func:`conv2d_offload_planned` — the plan-shaped kernel
-  ``kernels.emit`` maps ``LayerPlan``s onto: the window stays resident in
-  VMEM and each step DMAs only its **I_slice delta** (new columns within
-  a row, new rows at a zigzag row turn), *prefetched* one step ahead into
-  a separate delta buffer so the copy overlaps the previous step's MXU
-  work.  Double-buffering is exactly the part that is easy to get subtly
+  ``kernels.emit`` maps ``LayerPlan``s onto, on (H, W, C) arrays with
+  channels on the 128-lane axis: the window stays resident in VMEM and
+  each step DMAs only its **I_slice delta** (new columns within a row,
+  new rows at a zigzag row turn), *prefetched* one step ahead into a
+  separate delta buffer so the copy overlaps the previous step's MXU
+  work, then accumulates one (T, C_in) x (C_in, C_out) dot per kernel
+  tap.  Double-buffering is exactly the part that is easy to get subtly
   wrong (a dropped wait, a prefetch aimed at the live window), which is
   why ``kerncheck`` proves its DMA trace hazard-free and its per-step
   regions equal to the plan's I_slices before the kernel is trusted.
+  It compiles for the TPU (``tests/test_tpu_compile.py``).
 
-The MAC loop is an im2col-in-VMEM followed by one MXU ``jnp.dot``:
-(T, C_in*H_K*W_K) x (C_in*H_K*W_K, C_out).  On real hardware T and C_out
-should be padded to MXU lanes (multiples of 128); ``ops.conv2d`` handles
-padding.  Validated with ``interpret=True`` on CPU against ``ref.conv2d``.
+Off the TPU both run in interpret mode (``kernels.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -50,7 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import KernelShapeError
+from repro.kernels import KernelShapeError, resolve_interpret
 
 # Step cases of the planned kernel (shared with the static checker).
 CASE_FULL = "full"          # DMA the whole window (first step / no overlap)
@@ -154,11 +157,13 @@ def _im2col_dot(win_buf, w_ref, o_ref, *, t_run: int, s_w: int, w_k: int):
     o_ref[...] = out.T[:, None, :].astype(o_ref.dtype)
 
 
-def _conv_geometry(x: jax.Array, w: jax.Array, t_run: int,
-                   s_h: int, s_w: int) -> tuple[int, int, int, int, int]:
-    """Validate shapes; return (n, h_k, w_k, h_out, w_out_tiles)."""
-    c_in, h_in, w_in = x.shape
-    n, c_in2, h_k, w_k = w.shape
+def _conv_geometry(x_shape: tuple[int, ...], w_shape: tuple[int, ...],
+                   t_run: int, s_h: int, s_w: int
+                   ) -> tuple[int, int, int, int, int]:
+    """Validate (C_in, H_in, W_in) input and (N, C_in, H_K, W_K) kernel
+    shapes; return (n, h_k, w_k, h_out, w_out_tiles)."""
+    c_in, h_in, w_in = x_shape
+    n, c_in2, h_k, w_k = w_shape
     if c_in != c_in2:
         raise KernelShapeError(
             f"input has {c_in} channels but kernels expect {c_in2}")
@@ -183,7 +188,7 @@ def _out_index_map(w_out_tiles: int, zigzag: bool):
 def conv2d_offload(x: jax.Array, w: jax.Array, *,
                    t_run: int, s_h: int = 1, s_w: int = 1,
                    order: str = "zigzag",
-                   interpret: bool = True) -> jax.Array:
+                   interpret: bool | None = None) -> jax.Array:
     """S1 Pallas convolution (full-window DMA per step).
 
     Args:
@@ -194,7 +199,8 @@ def conv2d_offload(x: jax.Array, w: jax.Array, *,
       order: "zigzag" (paper Sec 7.2) or "row" grid sweep.
     """
     c_in = x.shape[0]
-    n, h_k, w_k, h_out, w_out_tiles = _conv_geometry(x, w, t_run, s_h, s_w)
+    n, h_k, w_k, h_out, w_out_tiles = _conv_geometry(
+        x.shape, w.shape, t_run, s_h, s_w)
     t_in = t_in_cols(t_run, s_w, w_k)
     w_mat = w.reshape(n, -1).T          # (C_in*Hk*Wk, N)
 
@@ -215,21 +221,109 @@ def conv2d_offload(x: jax.Array, w: jax.Array, *,
                                        x.dtype),
         scratch_shapes=[pltpu.VMEM((c_in, h_k, t_in), x.dtype),
                         pltpu.SemaphoreType.DMA],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(x, w_mat)
 
 
 # --------------------------------------------------------------------- #
 # Planned kernel: resident window + prefetched I_slice deltas
 # --------------------------------------------------------------------- #
+#
+# Layout: channels on the 128-lane axis.  ``pixel_shape`` stores a
+# pixel's channels as whole 128-lane rows of 32-bit words: C_in padded to
+# a multiple of 128 lanes in f32, and to two rows of lanes in bf16 (a
+# packed bf16 tile pairs two rows; with one row per pixel it would pair
+# neighbouring pixels, and a window could not start at an odd column).
+# The input is (H, W, *pixel) in HBM, which Mosaic addresses one lane row
+# at a time, so a DMA cuts H and W at any offset.  The resident window is
+# (h_k, t_in, *pixel), the delta buffers are (h_k, nw, *pixel) and
+# (s_h, t_in, *pixel) (see ``_dma_buffer``), Λ is (h_k, w_k, *pixel,
+# C_out) and each step writes one (t_run, C_out) output block.  Every
+# VMEM slice below is static; only the HBM-side DMA offsets depend on the
+# grid index.
+
+def pixel_shape(c: int, dtype) -> tuple[int, ...]:
+    """How the planned kernel stores one pixel's ``c`` channels: ``(L,)``
+    lanes for 32-bit types, ``(2, L)`` rows x lanes for bf16; L is a
+    multiple of 128.  Channel ``k`` sits at row ``k // L``, lane
+    ``k % L`` (a plain reshape of the zero-padded channel axis)."""
+    rows = 4 // jnp.dtype(dtype).itemsize
+    lanes = -(-c // (rows * 128)) * 128
+    return (lanes,) if rows == 1 else (rows, lanes)
+
+
+def _to_pixels(a: jax.Array, axis: int, pix: tuple[int, ...]) -> jax.Array:
+    """Zero-pad ``a``'s channel ``axis`` and split it into ``pix``."""
+    size = 1
+    for d in pix:
+        size *= d
+    widths = [(0, 0)] * a.ndim
+    widths[axis] = (0, size - a.shape[axis])
+    a = jnp.pad(a, widths)
+    return a.reshape(a.shape[:axis] + pix + a.shape[axis + 1:])
+
+
+def _padded_vmem_bytes(shape: tuple[int, ...], dtype) -> int:
+    """Bytes a VMEM buffer takes once its last two dims are padded to the
+    (sublane, 128) tile: 8 sublanes for 32-bit types, 16 for bf16."""
+    *lead, rows, lanes = shape
+    itemsize = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // itemsize
+    n = -(-rows // sub) * sub * (-(-lanes // 128) * 128)
+    for d in lead:
+        n *= d
+    return n * itemsize
+
+
+def _dma_buffer(rows_cols: tuple[int, int], pix: tuple[int, ...]
+                ) -> tuple[int, ...]:
+    """Shape of a VMEM buffer that DMAs fill with ``rows_cols`` pixels.
+
+    With one lane row per pixel (32-bit types) the column dim is the
+    tiled sublane dim, and Mosaic sizes a DMA's wait by its destination
+    padded to whole sublane tiles.  So the column dim is allocated padded
+    to 8 and every DMA targets the exact ``[:, :cols]`` slice, whose size
+    equals the copy's."""
+    rows, cols = rows_cols
+    if len(pix) == 1:
+        cols = -(-cols // 8) * 8
+    return (rows, cols, *pix)
+
+
+# Mosaic's own scratch (spilled accumulators, relayout temporaries) on
+# top of the buffers the kernel declares.
+_VMEM_HEADROOM_BYTES = 1 << 20
+
+
+def _tap_dots(win_buf, w_ref, o_ref, *, t_run: int, s_w: int, h_k: int,
+              w_k: int, rows_used: int, precision):
+    """One (t_run, L) @ (L, C_out) MXU dot per kernel tap (and per pixel
+    row that holds channels), each over a static slice of the resident
+    window, accumulated in f32."""
+    acc = None
+    for kh in range(h_k):
+        for kw in range(w_k):
+            cols = pl.ds(kw, t_run) if s_w == 1 else \
+                pl.ds(kw, t_run, stride=s_w)
+            if win_buf.ndim == 3:
+                pairs = [(win_buf[kh, cols, :], w_ref[kh, kw])]
+            else:
+                pairs = [(win_buf[kh, cols, r, :], w_ref[kh, kw, r])
+                         for r in range(rows_used)]
+            for xs, ws in pairs:
+                part = jnp.dot(xs, ws, preferred_element_type=jnp.float32,
+                               precision=precision)
+                acc = part if acc is None else acc + part
+    o_ref[...] = acc.astype(o_ref.dtype)
+
 
 def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
                          sems, *,
                          t_run: int, s_h: int, s_w: int, h_k: int,
                          w_k: int, h_out: int, w_out_tiles: int,
-                         zigzag: bool):
+                         zigzag: bool, rows_used: int, precision):
     """One plan step: retire the prefetched delta, update the resident
-    window, prefetch the next step's delta, then im2col + MXU dot."""
+    window, prefetch the next step's delta, then the per-tap MXU dots."""
     i = pl.program_id(0)
     jt_raw = pl.program_id(1)
     tiles = w_out_tiles
@@ -256,10 +350,10 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
     @pl.when(full_cond)
     def _full():
         # No usable overlap with the previous window: synchronous fetch
-        # of the whole (C_in, H_K, t_in) box.
+        # of the whole (h_k, t_in) box of pixels.
         cp = pltpu.make_async_copy(
-            x_hbm.at[:, pl.ds(h0, h_k), pl.ds(w0, t_in)],
-            win_buf, sems.at[SEM_FULL])
+            x_hbm.at[pl.ds(h0, h_k), pl.ds(w0, t_in)],
+            win_buf.at[:, :t_in], sems.at[SEM_FULL])
         cp.start()
         cp.wait()
 
@@ -269,25 +363,28 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
             # Retire the row prefetch issued one step ago, shift the kept
             # rows up, splice the s_h new rows in at the bottom.
             pltpu.make_async_copy(
-                x_hbm.at[:, pl.ds(h0 + keep_rows, s_h), pl.ds(w0, t_in)],
-                row_buf, sems.at[SEM_ROW]).wait()
-            kept = win_buf[:, s_h:, :]
-            win_buf[:, :keep_rows, :] = kept
-            win_buf[:, keep_rows:, :] = row_buf[...]
+                x_hbm.at[pl.ds(h0 + keep_rows, s_h), pl.ds(w0, t_in)],
+                row_buf.at[:, :t_in], sems.at[SEM_ROW]).wait()
+            win_buf[:keep_rows, :t_in] = win_buf[s_h:, :t_in]
+            win_buf[keep_rows:, :t_in] = row_buf[:, :t_in]
 
     if col_delta:
-        @pl.when(within)
-        def _col():
-            # Retire the column prefetch, slide the kept ov_w columns to
-            # their position in the new window, splice the delta in.
-            right = moving_right(i, zigzag)
-            delta_off = ov_w * right        # right: [ov_w, t_in); left: [0, nw)
-            pltpu.make_async_copy(
-                x_hbm.at[:, pl.ds(h0, h_k), pl.ds(w0 + delta_off, nw)],
-                col_buf, sems.at[SEM_COL]).wait()
-            kept = win_buf[:, :, pl.ds(nw * right, ov_w)]
-            win_buf[:, :, pl.ds(nw * (1 - right), ov_w)] = kept
-            win_buf[:, :, pl.ds(delta_off, nw)] = col_buf[...]
+        # One branch per sweep direction, so every window slice is static:
+        # moving right keeps the window's last ov_w columns and appends
+        # the delta; moving left keeps its first ov_w and prepends it.
+        for right in ((True, False) if zigzag else (True,)):
+            @pl.when(within & (moving_right(i, zigzag) == right))
+            def _col(right=right):
+                delta_off = ov_w if right else 0
+                pltpu.make_async_copy(
+                    x_hbm.at[pl.ds(h0, h_k), pl.ds(w0 + delta_off, nw)],
+                    col_buf.at[:, :nw], sems.at[SEM_COL]).wait()
+                if right:
+                    win_buf[:, :ov_w] = win_buf[:, nw:t_in]
+                    win_buf[:, ov_w:t_in] = col_buf[:, :nw]
+                else:
+                    win_buf[:, nw:t_in] = win_buf[:, :ov_w]
+                    win_buf[:, :nw] = col_buf[:, :nw]
 
     # Prefetch the NEXT step's delta while this step computes — the
     # double-buffering whose soundness kerncheck proves (the copy writes
@@ -303,64 +400,92 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
         @pl.when((~is_last) & nxt_turn)
         def _prefetch_row():
             pltpu.make_async_copy(
-                x_hbm.at[:, pl.ds(h0_n + keep_rows, s_h),
-                         pl.ds(w0_n, t_in)],
-                row_buf, sems.at[SEM_ROW]).start()
+                x_hbm.at[pl.ds(h0_n + keep_rows, s_h), pl.ds(w0_n, t_in)],
+                row_buf.at[:, :t_in], sems.at[SEM_ROW]).start()
 
     if col_delta:
         @pl.when((~is_last) & (~nxt_turn))
         def _prefetch_col():
             delta_off_n = ov_w * moving_right(i_n, zigzag)
             pltpu.make_async_copy(
-                x_hbm.at[:, pl.ds(h0_n, h_k), pl.ds(w0_n + delta_off_n, nw)],
-                col_buf, sems.at[SEM_COL]).start()
+                x_hbm.at[pl.ds(h0_n, h_k), pl.ds(w0_n + delta_off_n, nw)],
+                col_buf.at[:, :nw], sems.at[SEM_COL]).start()
 
-    _im2col_dot(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w, w_k=w_k)
+    _tap_dots(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w, h_k=h_k,
+              w_k=w_k, rows_used=rows_used, precision=precision)
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "t_run", "s_h", "s_w", "order", "interpret"))
 def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
                            t_run: int, s_h: int = 1, s_w: int = 1,
                            order: str = "zigzag",
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool | None = None) -> jax.Array:
     """Plan-shaped S1 Pallas convolution: per-step DMA == plan I_slice.
 
-    Same arguments and result as :func:`conv2d_offload`; the difference
-    is the traffic contract — each grid step fetches exactly the pixels
-    the corresponding ``GroupedStrategy`` step charges to ``t_l`` (the
-    window overlap with the previous step stays resident in VMEM), and
-    the fetch is prefetched one step ahead.  ``kernels.emit`` maps
-    ``LayerPlan``s here; ``repro.analysis.kerncheck`` proves the
-    equivalence statically.
+    Args:
+      x: input (H_in, W_in, C_in) — already padded (paper Remark 2).
+      w: kernels (H_K, W_K, C_in, N).
+      t_run, s_h, s_w, order: as for :func:`conv2d_offload`.
+
+    Returns the (H_out, W_out, N) output.  The traffic contract: each
+    grid step fetches exactly the pixels the corresponding
+    ``GroupedStrategy`` step charges to ``t_l`` (the window overlap with
+    the previous step stays resident in VMEM), and the fetch is
+    prefetched one step ahead.  ``kernels.emit`` maps ``LayerPlan``s
+    here; ``repro.analysis.kerncheck`` proves the equivalence statically.
+    f32 operands are dotted at full precision; bf16 stays bf16 with f32
+    accumulation.
     """
     if order not in ("zigzag", "row"):
         raise KernelShapeError(f"unknown grid order {order!r}")
-    c_in = x.shape[0]
-    n, h_k, w_k, h_out, w_out_tiles = _conv_geometry(x, w, t_run, s_h, s_w)
+    h_in, w_in, c_in = x.shape
+    h_k, w_k, c_w, n = w.shape
+    _, _, _, h_out, w_out_tiles = _conv_geometry(
+        (c_in, h_in, w_in), (n, c_w, h_k, w_k), t_run, s_h, s_w)
     t_in = t_in_cols(t_run, s_w, w_k)
     nw = t_run * s_w
-    w_mat = w.reshape(n, -1).T
+    dt = x.dtype
+    precision = (jax.lax.Precision.HIGHEST if dt == jnp.float32
+                 else jax.lax.Precision.DEFAULT)
+    pix = pixel_shape(c_in, dt)
+    x = _to_pixels(x, 2, pix)
+    w = _to_pixels(w, 2, pix)
+    interpret = resolve_interpret(interpret)
+    if not interpret:
+        # XLA would keep a small input in VMEM; the kernel DMAs from HBM.
+        x = pltpu.with_memory_space_constraint(x, pltpu.HBM)
+    scratch = [_dma_buffer(s, pix) for s in (
+        (h_k, t_in),                                         # resident window
+        (h_k, nw),                                           # column delta
+        (max(1, min(s_h, h_k)), t_in),                       # row delta
+    )]
+    vmem_bytes = (2 * _padded_vmem_bytes(w.shape, w.dtype)   # Λ (2 buffers)
+                  + 2 * _padded_vmem_bytes((t_run, n), dt)   # output blocks
+                  + sum(_padded_vmem_bytes(s, dt) for s in scratch))
 
+    zig = order == "zigzag"
     kernel = functools.partial(
         _conv_planned_kernel, t_run=t_run, s_h=s_h, s_w=s_w, h_k=h_k,
-        w_k=w_k, h_out=h_out, w_out_tiles=w_out_tiles,
-        zigzag=(order == "zigzag"))
-    return pl.pallas_call(
+        w_k=w_k, h_out=h_out, w_out_tiles=w_out_tiles, zigzag=zig,
+        rows_used=-(-c_in // pix[-1]), precision=precision)
+    out = pl.pallas_call(
         kernel,
         grid=(h_out, w_out_tiles),
         in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),               # x stays in HBM
-            pl.BlockSpec((c_in * h_k * w_k, n), lambda i, jt: (0, 0)),  # Λ
+            pl.BlockSpec(memory_space=pltpu.HBM),            # x stays in HBM
+            pl.BlockSpec(w.shape, lambda i, jt: (0,) * w.ndim),  # Λ resident
         ],
-        out_specs=pl.BlockSpec((n, 1, t_run),
-                               _out_index_map(w_out_tiles,
-                                              order == "zigzag")),
-        out_shape=jax.ShapeDtypeStruct((n, h_out, w_out_tiles * t_run),
-                                       x.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((c_in, h_k, t_in), x.dtype),          # resident window
-            pltpu.VMEM((c_in, h_k, nw), x.dtype),            # column delta
-            pltpu.VMEM((c_in, max(1, min(s_h, h_k)), t_in), x.dtype),
-            pltpu.SemaphoreType.DMA((3,)),
-        ],
+        out_specs=pl.BlockSpec(
+            (None, None, t_run, n),
+            lambda i, jt: (i, eff_tile(i, jt, w_out_tiles, zig), 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((h_out, w_out_tiles, t_run, n), dt),
+        scratch_shapes=[*(pltpu.VMEM(s, dt) for s in scratch),
+                        pltpu.SemaphoreType.DMA((3,))],
+        # The prefetch crosses grid steps: the grid must run in order.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=vmem_bytes + _VMEM_HEADROOM_BYTES),
         interpret=interpret,
-    )(x, w_mat)
+    )(x, w)
+    return out.reshape(h_out, w_out_tiles * t_run, n)

@@ -23,12 +23,20 @@ refuses anything it cannot map *exactly*:
 The emitted kernel implements the layer's *gross* schedule (every input
 pixel from HBM, every output written back); inter-layer reuse savings
 are a schedule-level accounting on top and do not change the kernel.
+
+:func:`execute_network` runs a whole emitted plan as one jitted program
+on the kernels' (H, W, C) layout, with :func:`glue` (2x2 max-pool where
+the next layer's input is smaller, then zero padding) between layers;
+:func:`reference_network` is the plain f32 chain it is checked against.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import Sequence
 
 import jax
+import jax.numpy as jnp
 
 from repro.core.conv_spec import ConvSpec
 from repro.core.cost_model import HardwareModel
@@ -36,7 +44,7 @@ from repro.core.network_planner import LayerPlan, NetworkPlan, plan_network
 from repro.core.solver import SolveResult
 from repro.core.strategies import (
     GridMeta, GroupedStrategy, lower_bound, zigzag)
-from repro.kernels import KernelShapeError
+from repro.kernels import KernelShapeError, ref, resolve_interpret
 from repro.kernels.conv2d_offload import conv2d_offload_planned, t_in_cols
 
 
@@ -45,12 +53,15 @@ class KernelEmitError(ValueError):
 
 
 def kernel_vmem_elements(spec: ConvSpec, t_run: int) -> int:
-    """VMEM elements the emitted kernel actually occupies.
+    """VMEM elements the emitted kernel occupies, in the plan's units.
 
     The checker's kern/vmem convention: the resident Λ block (constant
     index_map — Pallas keeps one copy), the window/delta scratch buffers
-    exactly as ``conv2d_offload_planned`` allocates them, and two output
-    blocks (Pallas double-buffers blocks whose index_map moves).
+    ``conv2d_offload_planned`` allocates, and two output blocks (Pallas
+    double-buffers blocks whose index_map moves), each counted in logical
+    elements.  The kernel's tile-padded allocation is larger; it sizes
+    only the kernel's ``vmem_limit_bytes``, so solver choices do not
+    depend on the padding.
     """
     t_in = t_in_cols(t_run, spec.s_w, spec.w_k)
     nw = t_run * spec.s_w
@@ -80,17 +91,27 @@ class EmittedConv:
         return self.grid_meta.order
 
     def run(self, x: jax.Array, w: jax.Array, *,
-            interpret: bool = True) -> jax.Array:
-        """Execute the plan: x (C_in, H_in, W_in), w (N, C_in, Hk, Wk)."""
+            interpret: bool | None = None) -> jax.Array:
+        """Execute the plan: x (C_in, H_in, W_in), w (N, C_in, Hk, Wk)
+        -> (N, H_out, W_out).  Transposes to and from :meth:`run_hwc`."""
+        out = self.run_hwc(jnp.transpose(x, (1, 2, 0)),
+                           jnp.transpose(w, (2, 3, 1, 0)),
+                           interpret=interpret)
+        return jnp.transpose(out, (2, 0, 1))
+
+    def run_hwc(self, x: jax.Array, w: jax.Array, *,
+                interpret: bool | None = None) -> jax.Array:
+        """Execute the plan on the kernel's layout: x (H_in, W_in, C_in),
+        w (Hk, Wk, C_in, N) -> (H_out, W_out, N)."""
         spec = self.spec
-        if x.shape != (spec.c_in, spec.h_in, spec.w_in):
+        if x.shape != (spec.h_in, spec.w_in, spec.c_in):
             raise KernelShapeError(
                 f"layer {self.layer_index}: input {x.shape} != plan spec "
-                f"({spec.c_in}, {spec.h_in}, {spec.w_in})")
-        if w.shape != (spec.c_out, spec.c_in, spec.h_k, spec.w_k):
+                f"({spec.h_in}, {spec.w_in}, {spec.c_in})")
+        if w.shape != (spec.h_k, spec.w_k, spec.c_in, spec.c_out):
             raise KernelShapeError(
                 f"layer {self.layer_index}: kernels {w.shape} != plan "
-                f"spec ({spec.c_out}, {spec.c_in}, {spec.h_k}, {spec.w_k})")
+                f"spec ({spec.h_k}, {spec.w_k}, {spec.c_in}, {spec.c_out})")
         return conv2d_offload_planned(
             x, w, t_run=self.t_run, s_h=spec.s_h, s_w=spec.s_w,
             order=self.order, interpret=interpret)
@@ -182,3 +203,75 @@ def plan_emitable_network(specs, hw: HardwareModel, *, name: str,
     traffic-conservation rule compares against exactly that."""
     return plan_network(specs, hw, name=name, allow_reuse=False,
                         solve_fn=grid_solve, **kwargs)
+
+
+# --------------------------------------------------------------------- #
+# Whole-network execution
+# --------------------------------------------------------------------- #
+
+def glue(y: jax.Array, spec: ConvSpec) -> jax.Array:
+    """Adapt layer output ``y`` (H, W, C) to the next layer's ``spec``.
+
+    The planner treats pooling and padding between layers as free; this
+    is what they are on the executed path.  Where ``y`` is larger than
+    the next input (a stride-2 stage), a 2x2 max-pool halves it; then it
+    is zero-padded, centred, to ``spec.h_in`` x ``spec.w_in``."""
+    h, w, c = y.shape
+    if c != spec.c_in:
+        raise KernelShapeError(
+            f"{c} channels feed a layer that expects {spec.c_in}")
+    if h > spec.h_in or w > spec.w_in:
+        if h % 2 or w % 2:
+            raise KernelShapeError(f"cannot 2x2-pool a {h}x{w} map")
+        h, w = h // 2, w // 2
+        y = y.reshape(h, 2, w, 2, c).max(axis=(1, 3))
+    ph, pw = spec.h_in - h, spec.w_in - w
+    if ph < 0 or pw < 0:
+        raise KernelShapeError(
+            f"a {h}x{w} map does not fit input {spec.h_in}x{spec.w_in}")
+    return jnp.pad(y, ((ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2),
+                       (0, 0)))
+
+
+def execute_network(plan: NetworkPlan, x: jax.Array,
+                    weights: Sequence[jax.Array], *,
+                    interpret: bool | None = None) -> jax.Array:
+    """Run every layer of ``plan`` through its emitted kernel, in order,
+    as one jitted program.
+
+    x is the first layer's (C_in, H_in, W_in) input and ``weights[l]``
+    layer l's (N, C_in, Hk, Wk) kernels; returns the last layer's
+    (N, H_out, W_out) output.  The (C, H, W) <-> (H, W, C) transposes
+    happen once, at the network's edges."""
+    layers = tuple(emit_layer_kernel(lp) for lp in plan.layers)
+    if len(weights) != len(layers):
+        raise KernelShapeError(
+            f"{len(weights)} weight tensors for {len(layers)} layers")
+    return _execute(x, tuple(weights), layers=layers,
+                    interpret=resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("layers", "interpret"))
+def _execute(x, weights, *, layers: tuple[EmittedConv, ...],
+             interpret: bool) -> jax.Array:
+    h = jnp.transpose(x, (1, 2, 0))
+    for k, (layer, w) in enumerate(zip(layers, weights)):
+        if k:
+            h = glue(h, layer.spec)
+        h = layer.run_hwc(h, jnp.transpose(w, (2, 3, 1, 0)),
+                          interpret=interpret)
+    return jnp.transpose(h, (2, 0, 1))
+
+
+def reference_network(specs: Sequence[ConvSpec], x: jax.Array,
+                      weights: Sequence[jax.Array]) -> jax.Array:
+    """The plain f32 ``jax.numpy`` chain :func:`execute_network` is
+    checked against: ``ref.conv2d`` (full precision) per layer with the
+    same :func:`glue` between layers."""
+    h = jnp.asarray(x, jnp.float32)
+    for k, (spec, w) in enumerate(zip(specs, weights)):
+        if k:
+            h = jnp.transpose(glue(jnp.transpose(h, (1, 2, 0)), spec),
+                              (2, 0, 1))
+        h = ref.conv2d(h, jnp.asarray(w, jnp.float32), spec.s_h, spec.s_w)
+    return h

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels import KernelShapeError
+from repro.kernels import KernelShapeError, resolve_interpret
 
 _NEG_INF = -1e30
 
@@ -88,7 +88,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 
 def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
                      length: jax.Array | int | None = None, *,
-                     bkv: int = 512, interpret: bool = True) -> jax.Array:
+                     bkv: int = 512,
+                     interpret: bool | None = None) -> jax.Array:
     """q (G, D), k/v (S, D), optional valid ``length`` -> (G, D)."""
     g, d = q.shape
     s, d2 = k.shape
@@ -117,5 +118,5 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((g, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(length, q, k, v)

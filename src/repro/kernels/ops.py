@@ -2,7 +2,7 @@
 
 Each wrapper: pads to kernel-friendly shapes, consults ``core.planner`` for
 the offloading schedule when the caller does not pin one, dispatches to the
-Pallas kernel (interpret=True on CPU — the TPU path flips the flag), and
+Pallas kernel (interpret mode off the TPU, see ``resolve_interpret``), and
 unpads.  ``ref.py`` holds the oracles; tests sweep shapes/dtypes and
 assert_allclose kernel vs oracle.
 """
@@ -18,8 +18,6 @@ from repro.kernels import KernelShapeError
 from repro.kernels import block_matmul as _bm
 from repro.kernels import conv2d_offload as _conv
 from repro.kernels import flash_decode as _fd
-
-_INTERPRET = True          # CPU container; TPU deployments set False.
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
@@ -48,7 +46,7 @@ def conv2d(x: jax.Array, w: jax.Array, *, t_run: int | None = None,
     if pad_cols:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, pad_cols)))
     out = _conv.conv2d_offload(x, w, t_run=t_run, s_h=s_h, s_w=s_w,
-                               order=order, interpret=_INTERPRET)
+                               order=order)
     return out[:, :, :w_out]
 
 
@@ -68,8 +66,7 @@ def matmul(a: jax.Array, b: jax.Array, *, bm: int | None = None,
         order = order or p.order
     a = _pad_to(_pad_to(a, 0, bm), 1, bk)
     b = _pad_to(_pad_to(b, 0, bk), 1, bn)
-    out = _bm.block_matmul(a, b, bm=bm, bn=bn, bk=bk, order=order,
-                           interpret=_INTERPRET)
+    out = _bm.block_matmul(a, b, bm=bm, bn=bn, bk=bk, order=order)
     return out[:m, :n]
 
 
@@ -98,8 +95,7 @@ def decode_attention(q: jax.Array, k: jax.Array, v: jax.Array,
     kg = jnp.moveaxis(k, 2, 1)           # (B, H_kv, S, D)
     vg = jnp.moveaxis(v, 2, 1)
 
-    single = functools.partial(_fd.decode_attention, bkv=bkv,
-                               interpret=_INTERPRET)
+    single = functools.partial(_fd.decode_attention, bkv=bkv)
     per_head = jax.vmap(single, in_axes=(0, 0, 0, None))     # over H_kv
     per_batch = jax.vmap(per_head, in_axes=(0, 0, 0, 0))     # over B
     out = per_batch(qg, kg, vg, lengths)

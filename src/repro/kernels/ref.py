@@ -8,11 +8,15 @@ from jax import lax
 
 def conv2d(x: jax.Array, w: jax.Array, s_h: int = 1, s_w: int = 1
            ) -> jax.Array:
-    """(C_in, H_in, W_in) x (N, C_in, Hk, Wk) -> (N, H_out, W_out)."""
+    """(C_in, H_in, W_in) x (N, C_in, Hk, Wk) -> (N, H_out, W_out).
+
+    f32 at full precision: left at the default, a TPU runs an f32 conv
+    in bf16 passes and the oracle would be no tighter than bf16."""
     out = lax.conv_general_dilated(
         x[None].astype(jnp.float32), w.astype(jnp.float32),
         window_strides=(s_h, s_w), padding="VALID",
-        dimension_numbers=("NCHW", "OIHW", "NCHW"))
+        dimension_numbers=("NCHW", "OIHW", "NCHW"),
+        precision=lax.Precision.HIGHEST)
     return out[0].astype(x.dtype)
 
 

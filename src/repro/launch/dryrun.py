@@ -35,7 +35,7 @@ import time
 import jax
 
 from repro.launch import steps
-from repro.launch.mesh import enter_mesh, make_production_mesh
+from repro.launch.mesh import make_production_mesh
 from repro.models import registry
 from repro.models.common import SHAPES, Axes, cell_applicable
 
@@ -103,7 +103,7 @@ def lower_cell(arch: str, shape: str, multi_pod: bool):
                 "status": "skipped", "reason": why}
     mesh = make_production_mesh(multi_pod=multi_pod)
     axes = Axes.for_mesh(mesh)
-    with enter_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         if cell.kind == "train":
             jitted = steps.jit_train_step(api, axes, cell)

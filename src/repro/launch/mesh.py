@@ -3,83 +3,21 @@
 ``make_production_mesh`` is a FUNCTION so importing this module never
 touches jax device state.  Single pod: (data=16, model=16) = 256 chips.
 Multi-pod: a leading pure-DP "pod" axis (2 pods = 512 chips) — the lowest
-ICI-pressure placement for the slower inter-pod links (DESIGN.md §5)."""
+ICI-pressure placement for the slower inter-pod links (DESIGN.md §5).
+
+A mesh is made ambient with ``jax.set_mesh(mesh)`` (a context manager);
+jit then takes bare PartitionSpecs as in/out shardings."""
 from __future__ import annotations
 
-import contextlib
-
 import jax
-
-
-_ACTIVE_MESH = None      # legacy-path bookkeeping for as_shardings()
-
-
-def supports_ambient_partition_specs() -> bool:
-    """True when this jax lets jit in/out_shardings be bare PartitionSpecs
-    resolved against the ambient mesh (the set_mesh / use_mesh era)."""
-    return hasattr(jax, "set_mesh") or hasattr(jax.sharding, "use_mesh")
-
-
-@contextlib.contextmanager
-def _legacy_mesh_ctx(mesh):
-    global _ACTIVE_MESH
-    prev = _ACTIVE_MESH
-    _ACTIVE_MESH = mesh
-    try:
-        with mesh:               # 0.4.x: Mesh is the resource-env manager
-            yield mesh
-    finally:
-        _ACTIVE_MESH = prev
-
-
-def enter_mesh(mesh):
-    """Context manager making ``mesh`` the ambient mesh across jax versions.
-
-    * jax >= 0.5: ``jax.sharding.use_mesh`` (a real context manager that
-      restores the previous mesh — preferred over ``jax.set_mesh``, whose
-      bare-setter form on some versions cannot be undone);
-    * jax >= 0.6 without use_mesh: ``jax.set_mesh``;
-    * jax 0.4.x (this container): the ``Mesh`` object itself is the
-      resource-env context manager, and the mesh is recorded so
-      ``as_shardings`` can build concrete NamedShardings.
-    """
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    set_mesh = getattr(jax, "set_mesh", None)
-    if set_mesh is not None:
-        cm = set_mesh(mesh)
-        return cm if hasattr(cm, "__enter__") else \
-            contextlib.nullcontext(mesh)
-    return _legacy_mesh_ctx(mesh)
-
-
-def as_shardings(tree):
-    """Adapt a PartitionSpec pytree to what this jax's jit accepts.
-
-    New jax (ambient-mesh era): specs pass through untouched.  jax 0.4.x:
-    every PartitionSpec leaf is wrapped into a NamedSharding over the mesh
-    entered via ``enter_mesh`` (jit there rejects bare specs)."""
-    if tree is None or supports_ambient_partition_specs():
-        return tree
-    mesh = _ACTIVE_MESH
-    if mesh is None:
-        return tree
-    is_spec = lambda s: isinstance(s, jax.sharding.PartitionSpec)
-    return jax.tree.map(
-        lambda s: jax.sharding.NamedSharding(mesh, s) if is_spec(s) else s,
-        tree, is_leaf=is_spec)
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(shape))
-    except (ImportError, TypeError):
-        return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(shape))
 
 
 def make_smoke_mesh(devices: int | None = None):
@@ -87,9 +25,5 @@ def make_smoke_mesh(devices: int | None = None):
     n = devices or len(jax.devices())
     d = max(1, n // 2) if n > 1 else 1
     m = n // d
-    try:
-        from jax.sharding import AxisType
-        return jax.make_mesh((d, m), ("data", "model"),
-                             axis_types=(AxisType.Auto,) * 2)
-    except (ImportError, TypeError):
-        return jax.make_mesh((d, m), ("data", "model"))
+    return jax.make_mesh((d, m), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

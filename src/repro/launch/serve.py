@@ -15,8 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import enter_mesh, make_production_mesh, \
-    make_smoke_mesh
+from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import registry
 from repro.models.common import Axes
 
@@ -41,7 +40,7 @@ def serve(arch: str, *, smoke: bool = True, batch: int = 4,
         else:
             api = registry.get(arch)
             mesh = make_production_mesh(multi_pod=multi_pod)
-            mesh_ctx.enter_context(enter_mesh(mesh))
+            mesh_ctx.enter_context(jax.set_mesh(mesh))
             axes = Axes.for_mesh(mesh)
         return _serve_loop(api, axes, batch=batch, prompt_len=prompt_len,
                            gen_len=gen_len)

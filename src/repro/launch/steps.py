@@ -13,7 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.launch.mesh import as_shardings
 from repro.models.common import Axes, ShapeCell
 from repro.models.registry import ModelApi
 from repro.optim import adamw
@@ -124,8 +123,8 @@ def jit_train_step(api: ModelApi, axes: Axes, cell: ShapeCell):
     fn = make_train_step(api, axes, num_microbatches=micro)
     return jax.jit(
         fn,
-        in_shardings=as_shardings((pspecs, ospecs, bspecs)),
-        out_shardings=as_shardings((P(), P(), pspecs, ospecs)),
+        in_shardings=(pspecs, ospecs, bspecs),
+        out_shardings=(P(), P(), pspecs, ospecs),
         donate_argnums=(0, 1))
 
 
@@ -141,8 +140,8 @@ def jit_prefill_step(api: ModelApi, axes: Axes, cell: ShapeCell):
     cache_specs = _pspecs_of(api.cache_defs(cell.global_batch, cell.seq_len,
                                             axes))
     logits_spec = P(axes.batch if cell.global_batch > 1 else None, None)
-    return jax.jit(fn, in_shardings=as_shardings((pspecs, bspecs)),
-                   out_shardings=as_shardings((logits_spec, cache_specs)))
+    return jax.jit(fn, in_shardings=(pspecs, bspecs),
+                   out_shardings=(logits_spec, cache_specs))
 
 
 def jit_decode_step(api: ModelApi, axes: Axes, cell: ShapeCell):
@@ -151,8 +150,8 @@ def jit_decode_step(api: ModelApi, axes: Axes, cell: ShapeCell):
     fn = make_decode_step(api, axes)
     return jax.jit(
         fn,
-        in_shardings=as_shardings((pspecs, ispecs["cache"],
-                                   ispecs["tokens"], ispecs["pos"])),
+        in_shardings=(pspecs, ispecs["cache"], ispecs["tokens"],
+                      ispecs["pos"]),
         donate_argnums=(1,))
 
 

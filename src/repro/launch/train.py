@@ -24,8 +24,7 @@ import numpy as np
 from repro.checkpoint.checkpoint import CheckpointManager
 from repro.data.pipeline import DataConfig, Pipeline, SyntheticLM
 from repro.launch import steps as steps_mod
-from repro.launch.mesh import enter_mesh, make_production_mesh, \
-    make_smoke_mesh
+from repro.launch.mesh import make_production_mesh, make_smoke_mesh
 from repro.models import registry
 from repro.models.common import Axes, ShapeCell
 from repro.optim import adamw
@@ -44,7 +43,7 @@ def train(arch: str, *, smoke: bool = True, steps: int = 10,
         else:
             api = registry.get(arch)
             mesh = make_production_mesh(multi_pod=multi_pod)
-            mesh_ctx.enter_context(enter_mesh(mesh))
+            mesh_ctx.enter_context(jax.set_mesh(mesh))
             axes = Axes.for_mesh(mesh)
         return _train_loop(api, axes, steps=steps, batch=batch,
                            seq_len=seq_len, ckpt_dir=ckpt_dir,

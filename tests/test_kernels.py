@@ -162,11 +162,12 @@ def test_conv_planned_delta_fetch_matches_ref(order, c_in, h, w, n, kh, kw,
     the same geometry cases the static trace enumerates."""
     x = RNG.standard_normal((c_in, h, w)).astype(np.float32)
     k = RNG.standard_normal((n, c_in, kh, kw)).astype(np.float32)
-    out = _conv.conv2d_offload_planned(jnp.asarray(x), jnp.asarray(k),
-                                       t_run=t_run, s_h=sh, s_w=sw,
-                                       order=order, interpret=True)
+    out = _conv.conv2d_offload_planned(
+        jnp.asarray(x.transpose(1, 2, 0)), jnp.asarray(k.transpose(2, 3, 1, 0)),
+        t_run=t_run, s_h=sh, s_w=sw, order=order, interpret=True)
     exp = ref.conv2d(jnp.asarray(x), jnp.asarray(k), sh, sw)
-    np.testing.assert_allclose(out, exp, rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(out, np.transpose(exp, (1, 2, 0)),
+                               rtol=1e-4, atol=1e-4)
 
 
 def test_kernel_geometry_errors_are_typed():
@@ -175,11 +176,13 @@ def test_kernel_geometry_errors_are_typed():
     AssertionError, and survive python -O."""
     x = jnp.zeros((2, 8, 8), jnp.float32)
     k = jnp.zeros((3, 2, 3, 3), jnp.float32)
+    x_hwc = jnp.zeros((8, 8, 2), jnp.float32)
+    k_hwc = jnp.zeros((3, 3, 2, 3), jnp.float32)
     with pytest.raises(KernelShapeError):
-        _conv.conv2d_offload_planned(x, k, t_run=4, order="spiral",
+        _conv.conv2d_offload_planned(x_hwc, k_hwc, t_run=4, order="spiral",
                                      interpret=True)
     with pytest.raises(KernelShapeError):      # t_run does not divide w_out
-        _conv.conv2d_offload_planned(x, k, t_run=4, s_w=1, s_h=1,
+        _conv.conv2d_offload_planned(x_hwc, k_hwc, t_run=4, s_w=1, s_h=1,
                                      order="zigzag", interpret=True)
     with pytest.raises(KernelShapeError):      # channel mismatch
         _conv.conv2d_offload(x, jnp.zeros((3, 1, 3, 3), jnp.float32),

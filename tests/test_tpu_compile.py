@@ -1,0 +1,106 @@
+"""The emitted conv kernels and the network executor compile for a TPU v5e.
+
+Interpret mode cannot see what the chip's compiler refuses (unaligned
+blocks and slices, unsupported relayouts, an input left in VMEM), so
+these tests compile at the real ``resnet8`` and ``lenet5`` widths for a
+described ``v5e:2x2`` topology, with ``interpret=False``.  Nothing runs.
+The topology is described inside a fixture: only one process may load
+the TPU library, and only the worker that runs this file does.
+"""
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.analysis.kerncheck import network_budget
+from repro.configs.networks import NETWORKS
+from repro.kernels.conv2d_offload import conv2d_offload_planned
+from repro.kernels.emit import (
+    emit_layer_kernel, execute_network, plan_emitable_network)
+
+NETS = ("resnet8", "lenet5")
+DTYPES = ("float32", "bfloat16")
+
+
+@functools.cache
+def _plan(name):
+    specs = list(NETWORKS[name])
+    return plan_emitable_network(specs, network_budget(specs), name=name)
+
+
+def _distinct_layers():
+    """(network, layer index) of each distinct emitted kernel shape."""
+    seen, out = set(), []
+    for name in NETS:
+        for lp in _plan(name).layers:
+            e = emit_layer_kernel(lp)
+            key = (lp.spec, e.t_run, e.order)
+            if key not in seen:
+                seen.add(key)
+                out.append((name, lp.index))
+    return out
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip cannot be read back from the
+    persistent cache; keep it out of the cache."""
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("name,index", _distinct_layers())
+def test_planned_conv_compiles_for_v5e(one_chip, name, index, dtype):
+    lp = _plan(name).layers[index]
+    e, s = emit_layer_kernel(lp), lp.spec
+    x = jax.ShapeDtypeStruct((s.h_in, s.w_in, s.c_in), dtype,
+                             sharding=one_chip)
+    w = jax.ShapeDtypeStruct((s.h_k, s.w_k, s.c_in, s.c_out), dtype,
+                             sharding=one_chip)
+    fn = functools.partial(conv2d_offload_planned, t_run=e.t_run,
+                           s_h=s.s_h, s_w=s.s_w, order=e.order,
+                           interpret=False)
+    hlo = jax.jit(fn).lower(x, w).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    # the input is pinned to HBM (memory-space color 0): the kernel DMAs
+    # its windows out of HBM and would misread an input placed in VMEM
+    assert '"input_memory_space_colors":[{"operand_index":"0","color":"0"' \
+        in hlo
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("name", NETS)
+def test_execute_network_compiles_for_v5e(one_chip, name, dtype):
+    plan = _plan(name)
+    specs = [lp.spec for lp in plan.layers]
+    s0 = specs[0]
+    x = jax.ShapeDtypeStruct((s0.c_in, s0.h_in, s0.w_in), dtype,
+                             sharding=one_chip)
+    ws = [jax.ShapeDtypeStruct((s.c_out, s.c_in, s.h_k, s.w_k), dtype,
+                               sharding=one_chip) for s in specs]
+    compiled = jax.jit(lambda x, ws: execute_network(
+        plan, x, ws, interpret=False)).lower(x, ws).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= len(specs)
+    last = specs[-1]
+    assert compiled.out_info.shape == (last.c_out, last.h_out, last.w_out)
+    assert compiled.out_info.dtype == jnp.dtype(dtype)
