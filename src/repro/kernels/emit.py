@@ -28,6 +28,10 @@ are a schedule-level accounting on top and do not change the kernel.
 on the kernels' (H, W, C) layout, with :func:`glue` (2x2 max-pool where
 the next layer's input is smaller, then zero padding) between layers;
 :func:`reference_network` is the plain f32 chain it is checked against.
+Each call writes its two host phases, emission and launch, as
+:data:`SPANS` into a JAX profiler trace when one is recording, and each
+trace of the jitted program counts ``executor/traces`` in
+``repro.obs.metrics.REGISTRY``.
 """
 from __future__ import annotations
 
@@ -46,6 +50,11 @@ from repro.core.strategies import (
     GridMeta, GroupedStrategy, lower_bound, zigzag)
 from repro.kernels import KernelShapeError, ref, resolve_interpret
 from repro.kernels.conv2d_offload import conv2d_offload_planned, t_in_cols
+
+
+#: Host spans of :func:`execute_network`, in the order a call opens them:
+#: emitting every layer's kernel, then launching the jitted program.
+SPANS = ("executor.emit", "executor.launch")
 
 
 class KernelEmitError(ValueError):
@@ -243,17 +252,23 @@ def execute_network(plan: NetworkPlan, x: jax.Array,
     layer l's (N, C_in, Hk, Wk) kernels; returns the last layer's
     (N, H_out, W_out) output.  The (C, H, W) <-> (H, W, C) transposes
     happen once, at the network's edges."""
-    layers = tuple(emit_layer_kernel(lp) for lp in plan.layers)
+    with jax.profiler.TraceAnnotation("executor.emit"):
+        layers = tuple(emit_layer_kernel(lp) for lp in plan.layers)
     if len(weights) != len(layers):
         raise KernelShapeError(
             f"{len(weights)} weight tensors for {len(layers)} layers")
-    return _execute(x, tuple(weights), layers=layers,
-                    interpret=resolve_interpret(interpret))
+    with jax.profiler.TraceAnnotation("executor.launch"):
+        return _execute(x, tuple(weights), layers=layers,
+                        interpret=resolve_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("layers", "interpret"))
 def _execute(x, weights, *, layers: tuple[EmittedConv, ...],
              interpret: bool) -> jax.Array:
+    # Runs only while JAX traces the program, i.e. on a jit-cache miss.
+    # Lazy import: repro.obs imports this module (through kerncheck).
+    from repro.obs.metrics import REGISTRY
+    REGISTRY.incr("executor/traces")
     h = jnp.transpose(x, (1, 2, 0))
     for k, (layer, w) in enumerate(zip(layers, weights)):
         if k:

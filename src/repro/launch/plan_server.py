@@ -43,7 +43,6 @@ from repro.core import solver as solver_mod
 from repro.core.cost_model import Topology
 from repro.core.multichip import plan_multichip_network
 from repro.core.network_planner import InfeasibleNetworkError
-from repro.obs.metrics import REGISTRY
 from repro.plancache import codec as codec_mod
 from repro.plancache import store as store_mod
 
@@ -96,7 +95,6 @@ class PlanService:
         if q.network not in NETWORKS:
             raise KeyError(f"unknown network {q.network!r}; "
                            f"registered: {sorted(NETWORKS)}")
-        REGISTRY.incr("plan_server/queries")
         stats0 = solver_mod.cache_stats()
         t0 = time.perf_counter()
         cluster = make_cluster(q.n_chips, nbop_pe=q.nbop_pe,
@@ -168,7 +166,6 @@ class PlanService:
                         nbop_pe=nbop_pe, polish_iters=polish_iters,
                         polish_restarts=polish_restarts,
                         rng_seed=rng_seed)))
-                    REGISTRY.incr("plan_server/scenarios")
         return rows
 
     def cache_stats(self) -> dict[str, Any]:

@@ -27,8 +27,7 @@ Durability rules:
   time, never correctness.
 
 Counters (hits / misses / writes / evictions / corruptions / stale /
-warm adoption) are kept per store instance and mirrored into the
-``repro.obs.metrics`` registry under ``plancache/``.
+warm adoption) are kept per store instance (:meth:`PlanStore.stats`).
 """
 from __future__ import annotations
 
@@ -129,13 +128,10 @@ class PlanStore:
         except OSError:
             pass
         self.evictions += 1
-        _metric("evictions")
         if stale:
             self.stale += 1
-            _metric("stale")
         else:
             self.corruptions += 1
-            _metric("corruptions")
 
     # -- public API ---------------------------------------------------- #
 
@@ -148,7 +144,6 @@ class PlanStore:
         path = self.entry_path(kind, family_digest, canonical_digest(key))
         if not path.exists():
             self.misses += 1
-            _metric("misses")
             return None
         try:
             payload = self.load_entry(path)
@@ -160,15 +155,12 @@ class PlanStore:
         except CacheSchemaError:
             self._evict(path, stale=True)
             self.misses += 1
-            _metric("misses")
             return None
         except CacheCorruptionError:
             self._evict(path)
             self.misses += 1
-            _metric("misses")
             return None
         self.hits += 1
-        _metric("hits")
         return value
 
     def put(self, kind: str, key: dict, family_digest: str,
@@ -196,7 +188,6 @@ class PlanStore:
                 pass
             return
         self.writes += 1
-        _metric("writes")
 
     def neighbors(self, kind: str, family_digest: str, *,
                   exclude_key: dict | None = None,
@@ -244,13 +235,6 @@ class PlanStore:
             "warm_considered": self.warm_considered,
             "warm_adopted": self.warm_adopted,
         }
-
-
-def _metric(name: str, amount: "int | float" = 1) -> None:
-    # lazy import: keep the store importable without pulling repro.obs in
-    # contexts that only want the file layer
-    from repro.obs.metrics import REGISTRY
-    REGISTRY.incr(f"plancache/{name}", amount)
 
 
 _active: PlanStore | None = None
