@@ -28,15 +28,24 @@ are a schedule-level accounting on top and do not change the kernel.
 on the kernels' (H, W, C) layout, with :func:`glue` (2x2 max-pool where
 the next layer's input is smaller, then zero padding) between layers;
 :func:`reference_network` is the plain f32 chain it is checked against.
-Each call writes its two host phases, emission and launch, as
-:data:`SPANS` into a JAX profiler trace when one is recording, and each
-trace of the jitted program counts ``executor/traces`` in
-``repro.obs.metrics.REGISTRY``.
+A plan is emitted once per plan object: the first call with a plan
+runs :func:`emit_layer_kernel` for each layer and keeps the result,
+keyed by the plan's identity (never its content, whose hash walks every
+group), and every later call with the same object reads it back.  The
+entry holds only a weak reference to the plan and is dropped when the
+plan is freed; a plan that emission refuses is not kept and raises on
+every call.  Each call writes its two host phases, the emission (or its
+lookup) and the launch, as :data:`SPANS` into a JAX profiler trace when
+one is recording.  In ``repro.obs.metrics.REGISTRY`` each call counts
+one of ``executor/emit_hits`` and ``executor/emit_misses`` (the calls
+that ran emission), and each trace of the jitted program counts
+``executor/traces``.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
+import weakref
 from typing import Sequence
 
 import jax
@@ -53,7 +62,8 @@ from repro.kernels.conv2d_offload import conv2d_offload_planned, t_in_cols
 
 
 #: Host spans of :func:`execute_network`, in the order a call opens them:
-#: emitting every layer's kernel, then launching the jitted program.
+#: emitting every layer's kernel (or reading them back), then launching
+#: the jitted program.
 SPANS = ("executor.emit", "executor.launch")
 
 
@@ -251,15 +261,48 @@ def execute_network(plan: NetworkPlan, x: jax.Array,
     x is the first layer's (C_in, H_in, W_in) input and ``weights[l]``
     layer l's (N, C_in, Hk, Wk) kernels; returns the last layer's
     (N, H_out, W_out) output.  The (C, H, W) <-> (H, W, C) transposes
-    happen once, at the network's edges."""
+    happen once, at the network's edges.
+
+    The layers are emitted on the first call with ``plan`` and kept,
+    keyed by the plan object, until the plan is freed; every later call
+    with the same object reads them back.  Each call counts one of
+    ``executor/emit_hits`` and ``executor/emit_misses`` in
+    ``repro.obs.metrics.REGISTRY``."""
     with jax.profiler.TraceAnnotation("executor.emit"):
-        layers = tuple(emit_layer_kernel(lp) for lp in plan.layers)
+        layers = _emitted_layers(plan)
     if len(weights) != len(layers):
         raise KernelShapeError(
             f"{len(weights)} weight tensors for {len(layers)} layers")
     with jax.profiler.TraceAnnotation("executor.launch"):
         return _execute(x, tuple(weights), layers=layers,
                         interpret=resolve_interpret(interpret))
+
+
+#: ``id(plan)`` -> (weak reference to the plan, its emitted layers), for
+#: every live plan :func:`execute_network` has emitted.
+_EMITTED: dict[int, tuple[weakref.ref, tuple[EmittedConv, ...]]] = {}
+
+
+def _emitted_layers(plan: NetworkPlan) -> tuple[EmittedConv, ...]:
+    """``plan``'s emitted layers, emitting them on the first call with
+    this plan object."""
+    # Lazy import, as in _execute.
+    from repro.obs.metrics import REGISTRY
+    key = id(plan)
+    entry = _EMITTED.get(key)
+    if entry is not None and entry[0]() is plan:
+        REGISTRY.incr("executor/emit_hits")
+        return entry[1]
+    REGISTRY.incr("executor/emit_misses")
+    layers = tuple(emit_layer_kernel(lp) for lp in plan.layers)
+
+    def forget(ref: weakref.ref) -> None:
+        # The plan is being freed; its id may be reused after this.
+        if _EMITTED.get(key, (None,))[0] is ref:
+            del _EMITTED[key]
+
+    _EMITTED[key] = (weakref.ref(plan, forget), layers)
+    return layers
 
 
 @functools.partial(jax.jit, static_argnames=("layers", "interpret"))
