@@ -1,5 +1,10 @@
 """Work a configuration's conv layers must do, from its shapes alone.
 
+Every layer of a configuration is a conv layer, and only its shape keys
+(``c_in, h_in, w_in, n_kernels, h_k, w_k, s_h, s_w``) are read.  What
+other keys tell the program, such as residual adds, activations and
+pools, is not counted.
+
 The roofline and the utilization divide these counts by measured time,
 so they count what the convolution needs and nothing a kernel adds: no
 lane padding, no re-fetched window columns.  Bytes are each layer's

@@ -13,12 +13,21 @@ spelled out so that it reads the same on any backend.  The parts are cut
 with ``reduce_precision``: XLA may drop a float32 -> bfloat16 -> float32
 round trip of casts as excess precision, which would leave the low part
 zero and the control one pass.
+
+It reads a layer's shape keys and nothing else, so it refuses a
+configuration whose layers carry any other key: such a key tells the
+program something (a join, an activation, a pool) that this chain would
+leave out.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+#: The keys of a layer this reference reads.
+READS = frozenset(("c_in", "h_in", "w_in", "n_kernels", "h_k", "w_k",
+                   "s_h", "s_w"))
 
 
 def _contract(x, w, passes: int | None):
@@ -85,5 +94,11 @@ def forward(cfg: dict, x, weights, passes: int | None = None):
 
 
 def make_forward(cfg: dict, passes: int | None = None):
-    """``forward`` for ``cfg`` as one jitted function of (x, weights)."""
+    """``forward`` for ``cfg`` as one jitted function of (x, weights);
+    ValueError where a layer holds a key this reference does not read."""
+    for k, layer in enumerate(cfg["layers"]):
+        unread = sorted(set(layer) - READS)
+        if unread:
+            raise ValueError(f"conv_chain reads only the shape keys; layer "
+                             f"{k} also holds {unread}")
     return jax.jit(lambda x, ws: forward(cfg, x, ws, passes))

@@ -5,22 +5,60 @@
 
 Run from the root of a checkout, on a machine whose first JAX device is
 a TPU (anything else exits non-zero without a result).  The cell names a
-configuration (``configs/<name>.json``: layer shapes, dtype, planner
-budget, the reference that checks it and the limit of each number
-compared) and a traffic mix (``traffic/<name>.json``: the runner module
-under ``runners/`` and its parameters).  Each metric is read by
+configuration (``configs/<name>.json``: layers, dtype, planner budget,
+the reference that checks it and the limit of each number compared) and
+a traffic mix (``traffic/<name>.json``: the runner module under
+``runners/`` and its parameters).  Each metric is read by
 ``metrics/<name>.py``, where a name ``base.split`` is read by
 ``metrics/<base>.py``.  Nothing here names a configuration, a mix or a
 metric.
 
-Set-up plans the network with ``plan_emitable_network(verify=True)``,
-makes the weights and a pool of distinct input images on the device
-from ``--seed``, calls the network once (compiling it, or loading it
-from the compile cache under ``.jax_compile_cache/``) and warms up the
-runner.  The window then drives ``repro.kernels.emit.execute_network``
-directly, one call per request, for ``--seconds``.  With ``--trace 1`` a
-second, traced window of at most ``TRACE_SECONDS`` follows, and the
-per-layer metrics are printed in place of the end-to-end ones.
+Of each layer the harness reads only the :data:`SHAPE_KEYS`: layer 0's
+``c_in, h_in, w_in`` give the image; each layer gets one weight
+``(n_kernels, c_in, h_k, w_k)`` drawn from N(0, 1/fan_in); ``counts.py``
+counts each layer's work from them; and the number of layers is the
+number of conv kernels in a network program.  Any other key belongs to
+the program and to the configuration's reference, and is passed through
+untouched.  Set-up plans the network by one of two routes, chosen by the
+keys alone:
+
+* every layer holds only shape keys: ``ConvSpec(**layer)`` for each,
+  then ``repro.kernels.emit.plan_emitable_network(specs, hw, name=...,
+  verify=True)``;
+* some layer holds another key: the layer list as written, a list of
+  dicts in file order, goes to ``repro.kernels.emit.plan_layers(layers,
+  hw, *, name, verify=True)``.  A program without ``plan_layers``, or a
+  ``plan_layers`` that refuses the layers by raising one of
+  :data:`REFUSALS` (``ValueError``, which the program's planning and
+  emission errors subclass, or ``NotImplementedError``), refuses the
+  run before any timing: exit ``EXIT_REFUSED``, the program's words on
+  standard error, no result.  Any other exception is a fault and
+  propagates with its traceback.
+
+Once the program has ``plan_layers``, a layer of shape keys alone is
+the case of it whose dicts hold nothing else: the change that adds it
+sends every configuration through it and deletes the ``ConvSpec``
+route, with ``tests/test_configs.py``'s plan pins as its test.
+
+Either way the program's side is this.  ``execute_network(plan, x,
+weights)`` runs the plan, with ``x`` the ``(C, H, W)`` input of
+``layers[0]`` and ``weights`` one tensor per layer in file order.  The
+whole network is one jitted program whose module name contains
+``_execute``, and its conv kernels are ``conv2d_offload_planned`` calls
+in the file's layer order (``xplane.reduce`` and ``pred_error`` rely on
+both).  ``plan.layers`` holds one layer per configuration layer, each
+with a Def-3 ``gross_duration``.  A configuration whose layers carry
+program keys names its own reference module, ``references/<name>.py``,
+with ``make_forward(cfg, passes=None|3)`` as ``conv_chain`` has it.
+
+Set-up then makes the weights and a pool of distinct input images on
+the device from ``--seed``, calls the network once (compiling it, or
+loading it from the compile cache under ``.jax_compile_cache/``) and
+warms up the runner.  The window then drives
+``repro.kernels.emit.execute_network`` directly, one call per request,
+for ``--seconds``.  With ``--trace 1`` a second, traced window of at
+most ``TRACE_SECONDS`` follows, and the per-layer metrics are printed in
+place of the end-to-end ones.
 
 Once the windows have closed, a sample of the window's outputs drawn
 from the seed is compared with the configuration's plain float32
@@ -35,6 +73,7 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import copy  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
@@ -46,13 +85,22 @@ from pathlib import Path  # noqa: E402
 
 HERE = Path(__file__).resolve().parent
 ROOT = HERE.parents[1]
+BENCH_FILE = ROOT / "BENCHMARK.json"
 WARMUP_REQUESTS = 64
 TRACE_SECONDS = 2.0
 CHECK_SAMPLE = 128
+BREAKDOWN_ENTRIES = 10
 UNSTACK = 256
 EXIT_REFUSED = 2
 CACHE_HIT = "/jax/compilation_cache/cache_hits"
 CACHE_MISS = "/jax/compilation_cache/cache_misses"
+#: The only keys of a configuration's layer that the harness reads.
+SHAPE_KEYS = ("c_in", "h_in", "w_in", "n_kernels", "h_k", "w_k", "s_h",
+              "s_w")
+#: The program's counters logged over the timed window.
+COUNTERS = ("executor/traces", "executor/emit_misses", "executor/emit_hits")
+#: What ``plan_layers`` raises to refuse a configuration.
+REFUSALS = (ValueError, NotImplementedError)
 
 
 class BenchError(Exception):
@@ -79,13 +127,18 @@ def _read_json(path: Path) -> dict:
 
 def load_cell(workload: str):
     """(benchmark, cell, configuration, traffic) for ``workload``."""
-    bench = _read_json(ROOT / "BENCHMARK.json")
+    bench = _read_json(BENCH_FILE)
     cells = {c["name"]: c for c in bench["workloads"]}
     if workload not in cells:
-        raise BenchError(f"no workload {workload!r} in BENCHMARK.json")
+        raise BenchError(f"no workload {workload!r} in {BENCH_FILE.name}")
     cell = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     cfg = _read_json(ROOT / configs[cell["config"]]["file"])
+    for k, layer in enumerate(cfg["layers"]):
+        missing = [key for key in SHAPE_KEYS if key not in layer]
+        if missing:
+            raise BenchError(f"layer {k} of {cfg['name']} lacks the shape "
+                             f"keys {missing}")
     traffic = _read_json(HERE / "traffic" / f"{cell['traffic']}.json")
     return bench, cell, cfg, traffic
 
@@ -208,8 +261,21 @@ def max_rel_err(outs, refs) -> float | None:
     return float((diff / scale).max())
 
 
-def check(cfg: dict, sample: Sample, images: list, weights) -> dict:
-    """The numbers compared, each with its limit."""
+def reference_forward(cfg: dict):
+    """The configuration's reference as one function of (x, weights), or
+    BenchError where the reference refuses the configuration."""
+    module = load_module("references", cfg["reference"])
+    try:
+        return module.make_forward(cfg)
+    except ValueError as e:
+        raise BenchError(f"reference {cfg['reference']!r} refuses "
+                         f"{cfg['name']}: {e}") from e
+
+
+def check(cfg: dict, forward, sample: Sample, images: list,
+          weights) -> dict:
+    """The numbers compared, each with its limit; ``forward`` is the
+    reference's."""
     import jax.numpy as jnp
     import numpy as np
     items = sorted(sample.items, key=lambda item: item[0])
@@ -218,7 +284,6 @@ def check(cfg: dict, sample: Sample, images: list, weights) -> dict:
         return {"max_rel_err": {"value": None, "limit": limit}}
     xs = jnp.stack([images[k % len(images)] for k, _ in items])
     outs = np.stack([np.asarray(out, np.float32) for _, out in items])
-    forward = load_module("references", cfg["reference"]).make_forward(cfg)
     refs = np.asarray(forward(xs, weights), np.float32)
     return {"max_rel_err": {"value": max_rel_err(outs, refs),
                             "limit": limit}}
@@ -249,18 +314,24 @@ def trace_window(runner, call, images, traffic, log_dir: str,
 
 
 def traced_window(runner, call, images, traffic, seconds: float,
-                  n_layers: int) -> tuple[dict, dict]:
-    """A traced window, its trace reduced and deleted."""
+                  n_layers: int, program_spans) -> tuple[dict, dict, dict]:
+    """A traced window, its trace read once, reduced and deleted: (runner
+    result, ``xplane.reduce``'s reduction, ``spans.reduce``'s over the
+    runner's and the program's spans)."""
+    import spans
     import xplane
+    runner_spans = getattr(runner, "SPANS", ())
     log_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
     try:
         result, path = trace_window(runner, call, images, traffic, log_dir,
                                     seconds=seconds)
-        reduced = xplane.reduce(path, n_layers=n_layers,
-                                span_names=getattr(runner, "SPANS", ()))
+        events = xplane.events(path)
+        reduced = xplane.reduce(events, n_layers=n_layers)
+        by_span = spans.reduce(events, program_spans=program_spans,
+                               span_names=runner_spans)
     finally:
         shutil.rmtree(log_dir, ignore_errors=True)
-    return result, reduced
+    return result, reduced, by_span
 
 
 def open_device(cell: dict):
@@ -302,11 +373,37 @@ class Cache:
         return self.hits + self.misses
 
 
+def plan_network(cfg: dict, emit):
+    """The program's plan of ``cfg``'s layers, by the route its keys
+    choose (module docstring), or BenchError where the program cannot
+    plan a configuration that carries keys of its own."""
+    from repro.core.cost_model import HardwareModel
+    hw = HardwareModel(nbop_pe=int(cfg["budget"]["nbop_pe"]),
+                       size_mem=int(cfg["budget"]["size_mem"]))
+    layers = cfg["layers"]
+    if all(set(layer) <= set(SHAPE_KEYS) for layer in layers):
+        from repro.core.conv_spec import ConvSpec
+        specs = [ConvSpec(**layer) for layer in layers]
+        return emit.plan_emitable_network(specs, hw, name=cfg["name"],
+                                          verify=True)
+    plan_layers = getattr(emit, "plan_layers", None)
+    if plan_layers is None:
+        raise BenchError(f"{cfg['name']}'s layers carry keys besides the "
+                         f"shape keys, and the program has no "
+                         f"{emit.__name__}.plan_layers to plan them")
+    try:
+        # a copy, so that the program cannot change what the harness reads
+        return plan_layers(copy.deepcopy(layers), hw, name=cfg["name"],
+                           verify=True)
+    except REFUSALS as e:
+        raise BenchError(f"{emit.__name__}.plan_layers refuses "
+                         f"{cfg['name']}: {type(e).__name__}: {e}") from e
+
+
 def set_up(cfg: dict, traffic: dict, seed: int, log) -> dict:
     """Plan, make the inputs, compile and warm up; the pieces a window
-    needs and the seconds each phase took."""
-    from repro.core.conv_spec import ConvSpec
-    from repro.core.cost_model import HardwareModel
+    needs and the seconds each phase took.  BenchError where the program
+    cannot plan the configuration."""
     from repro.kernels import emit
 
     cache = Cache()
@@ -314,11 +411,7 @@ def set_up(cfg: dict, traffic: dict, seed: int, log) -> dict:
     setup = {"import_s": clock() - T_START}
 
     t0 = clock()
-    specs = [ConvSpec(**layer) for layer in cfg["layers"]]
-    hw = HardwareModel(nbop_pe=int(cfg["budget"]["nbop_pe"]),
-                       size_mem=int(cfg["budget"]["size_mem"]))
-    plan = emit.plan_emitable_network(specs, hw, name=cfg["name"],
-                                      verify=True)
+    plan = plan_network(cfg, emit)
     setup["plan_s"] = clock() - t0
 
     t0 = clock()
@@ -344,7 +437,7 @@ def set_up(cfg: dict, traffic: dict, seed: int, log) -> dict:
         f"hits={cache.hits} misses={cache.misses}")
     return {"plan": plan, "weights": weights, "images": images,
             "call": call, "runner": runner, "setup": setup, "cache": cache,
-            "warm": warm}
+            "warm": warm, "program_spans": getattr(emit, "SPANS", ())}
 
 
 def parse_args(argv):
@@ -365,50 +458,63 @@ def main(argv=None) -> int:
     try:
         bench, cell, cfg, traffic = load_cell(args.workload)
         dev, peak = open_device(cell)
+        s = set_up(cfg, traffic, args.seed, log)
+        forward = reference_forward(cfg)
     except BenchError as e:
         log(f"chipbench: {e}")
         return EXIT_REFUSED
+    from repro.obs.metrics import REGISTRY
 
-    s = set_up(cfg, traffic, args.seed, log)
     runner, call, images = s["runner"], s["call"], s["images"]
     sample = Sample(CHECK_SAMPLE, args.seed)
     lookups = s["cache"].lookups
+    counted = [REGISTRY.get(name) for name in COUNTERS]
     window = runner.run(call, images, traffic, seconds=args.seconds,
                         on_output=sample.add)
+    counted = [REGISTRY.get(name) - c for name, c in zip(COUNTERS, counted)]
     stats = dev.memory_stats() or {}
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": int(cell["chips"]),
               "memory_peak_bytes": stats.get("peak_bytes_in_use")}
     log(f"window: {json.dumps(window)} compiles in window="
         f"{s['cache'].lookups - lookups}")
+    log("window counters: " + " ".join(
+        f"{name}={c:g}" for name, c in zip(COUNTERS, counted)))
 
-    trace = None
+    trace = by_span = breakdown = None
     failed = window["failed"] + s["warm"]["failed"]
     if args.trace:
-        traced, trace = traced_window(
+        traced, trace, by_span = traced_window(
             runner, call, images, traffic, min(TRACE_SECONDS, args.seconds),
-            len(cfg["layers"]))
+            len(cfg["layers"]), s["program_spans"])
         failed += traced["failed"]
         device["busy_s"] = trace["busy_s"]
         device["window_s"] = trace["window_s"]
         log(f"traced window: {json.dumps(traced)} images in trace="
             f"{trace['images']} layer_s={trace['layer_s']}")
+        log(f"traced spans: {json.dumps(by_span)}")
+        # the device's idle time by the innermost span that covered it,
+        # the program's spans inside the runner's
+        idle = sorted(([n, v] for n, v in by_span["idle_by_span"].items()
+                       if v > 0), key=lambda item: -item[1])
+        breakdown = {"device_ops": trace["device_ops"],
+                     "idle_gaps": idle[:BREAKDOWN_ENTRIES]}
 
     ctx = {"cfg": cfg, "peak": peak, "setup": s["setup"],
            "window": window, "trace": trace,
+           "spans": None if by_span is None else by_span["spans"],
            "predicted": [lp.gross_duration for lp in s["plan"].layers]}
     metrics = read_metrics(select_metrics(bench, cell["name"],
                                           bool(args.trace)), ctx)
 
     weights = s["weights"]
     del s, call
-    checks = check(cfg, sample, images, weights)
+    checks = check(cfg, forward, sample, images, weights)
     correct = failed == 0 and passed(checks)
     result = {"correct": correct, "attempted": window["attempted"],
               "failed": failed, "metrics": metrics, "device": device}
-    if trace is not None:
-        result["breakdown"] = {"device_ops": trace["device_ops"],
-                               "idle_gaps": trace["idle_gaps"]}
+    if breakdown is not None:
+        result["breakdown"] = breakdown
     result["checks"] = checks
     for name, c in checks.items():
         log(f"check {name}={c['value']!r} limit={c['limit']!r}")

@@ -50,24 +50,19 @@ def _idle_gaps(lines, launches, lo, hi):
     return gaps
 
 
-def reduce(path: str, *, program_spans, span_names=()) -> dict:
-    """Time inside each named span, and where the device sat idle.
+def reduce(trace, *, program_spans, span_names=()) -> dict:
+    """Time inside each named span, and where the device sat idle, from
+    ``xplane.events``' ``trace``.
 
     ``spans`` maps each of ``program_spans`` and ``span_names`` to the
     ``count`` of its spans inside the ``harness.window`` span and their
     summed seconds ``s``.  ``idle_by_span`` gives the device's idle
     seconds, averaged over the chips traced, by the innermost span that
     covered them: a program span before a runner span (among
-    ``span_names``), then ``other``.  Where a chip was traced its values
-    sum to ``xplane.reduce``'s ``window_s - busy_s``, and its runner-span values are
-    ``xplane.reduce``'s ``idle_gaps`` less the program spans inside
-    them."""
-    devices, host = xplane._events(path)
-    windows = [(s, e) for n, s, e in host if n == xplane.WINDOW_SPAN]
-    if len(windows) != 1:
-        raise ValueError(f"expected one {xplane.WINDOW_SPAN} span, found "
-                         f"{len(windows)}")
-    lo, hi = windows[0]
+    ``span_names``), then ``other``.  Its values sum to ``xplane.reduce``'s
+    ``window_s - busy_s``."""
+    devices, host = trace
+    lo, hi = xplane.window(host)
     names = tuple(program_spans) + tuple(span_names)
     spans = {name: {"count": 0, "s": 0.0} for name in names}
     for n, s, e in host:
@@ -98,3 +93,11 @@ def reduce(path: str, *, program_spans, span_names=()) -> dict:
             "idle_by_span": {n: ns * 1e-9 / chips
                              for n, ns in idle_ns.items()}}
 
+
+def mean_us(spans: dict | None, name: str) -> float | None:
+    """Mean microseconds of one span named ``name``, from ``reduce``'s
+    ``spans``; None where there is no such span (or no trace)."""
+    span = (spans or {}).get(name)
+    if not span or not span["count"]:
+        return None
+    return span["s"] / span["count"] * 1e6

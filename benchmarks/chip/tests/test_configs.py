@@ -37,13 +37,11 @@ def test_config_reproduces_the_registry(name, registered):
     ("lenet5-f32", [14, 10], {"full": 2, "row-delta": 36, "col-delta": 28}),
 ])
 def test_plan_at_the_configs_budget(name, t_runs, cases):
-    from repro.core.cost_model import HardwareModel
+    """The plan the harness makes, by its ``ConvSpec`` route."""
+    from repro.kernels import emit
     from repro.kernels.conv2d_offload import grid_sequence, step_case
-    from repro.kernels.emit import emit_layer_kernel, plan_emitable_network
-    cfg = _config(name)
-    hw = HardwareModel(nbop_pe=cfg["budget"]["nbop_pe"],
-                       size_mem=cfg["budget"]["size_mem"])
-    plan = plan_emitable_network(_specs(cfg), hw, name=name, verify=True)
+    from repro.kernels.emit import emit_layer_kernel
+    plan = run.plan_network(_config(name), emit)
     emitted = [emit_layer_kernel(lp) for lp in plan.layers]
     assert [e.t_run for e in emitted] == t_runs
     seen = Counter()

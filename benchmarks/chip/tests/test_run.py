@@ -27,15 +27,27 @@ def _assert_result(code, result, err, correct):
 
 
 def test_a_sound_run_is_correct(bench_run):
-    _assert_result(*bench_run(*CELL), correct=True)
+    code, result, err = bench_run(*CELL)
+    _assert_result(code, result, err, correct=True)
+    # the plan was emitted and compiled in set-up: the window only reads
+    # it back
+    counters, = [line for line in err.splitlines()
+                 if line.startswith("window counters: ")]
+    assert counters.startswith("window counters: executor/traces=0 "
+                               "executor/emit_misses=0 executor/emit_hits=")
+    assert int(counters.rsplit("=", 1)[1]) == result["attempted"]
 
 
 def test_a_traced_run_reports_per_layer_metrics(bench_run):
     code, result, _ = bench_run(*CELL, "--trace", "1")
     assert code == 0 and result["correct"] is True
-    # the CPU has no device plane: what reads the trace finds nothing
+    # the CPU has no device plane: what reads the device's events finds
+    # nothing, while the program's host spans are there
     assert set(result["metrics"]) == {"plan_s", "compile_s",
-                                      "dispatch_us.sync"}
+                                      "dispatch_us.sync", "emit_us.sync",
+                                      "launch_us.sync"}
+    assert 0 < result["metrics"]["emit_us.sync"]["value"]
+    assert 0 < result["metrics"]["launch_us.sync"]["value"]
     assert result["device"]["busy_s"] == 0
     assert list(result)[-1] == "checks" and "breakdown" in result
 

@@ -16,12 +16,13 @@ TRACES = [str(DATA / "lenet5-f32.sync.xplane.pb"),
 NEW = TRACES[1]
 
 
-def _reduce(path):
-    return spans.reduce(path, program_spans=SPANS, span_names=RUNNER)
+def _reduce(path, program_spans=SPANS):
+    return spans.reduce(xplane.events(path), program_spans=program_spans,
+                        span_names=RUNNER)
 
 
 def _host(path, name):
-    _, host = xplane._events(path)
+    _, host = xplane.events(path)
     return sorted((s, e) for n, s, e in host if n == name)
 
 
@@ -48,7 +49,7 @@ def test_program_spans_nest_in_one_dispatch_each():
 @pytest.mark.parametrize("path", TRACES)
 def test_idle_by_span_sums_to_the_idle_window(path):
     r = _reduce(path)
-    base = xplane.reduce(path, n_layers=2, span_names=RUNNER)
+    base = xplane.reduce(xplane.events(path), n_layers=2)
     assert r["window_s"] == base["window_s"]
     assert sum(r["idle_by_span"].values()) == pytest.approx(
         base["window_s"] - base["busy_s"], rel=1e-9)
@@ -57,8 +58,7 @@ def test_idle_by_span_sums_to_the_idle_window(path):
 @pytest.mark.parametrize("path", TRACES)
 def test_runner_idle_is_idle_gaps_less_the_program_spans(path):
     r = _reduce(path)
-    gaps = dict(xplane.reduce(path, n_layers=2,
-                              span_names=RUNNER)["idle_gaps"])
+    gaps = _reduce(path, program_spans=())["idle_by_span"]
     idle = r["idle_by_span"]
     program = sum(idle[n] for n in SPANS)
     assert idle["request.dispatch"] + program == pytest.approx(
@@ -72,6 +72,16 @@ def test_a_trace_without_program_spans_reads_none():
     r = _reduce(TRACES[0])
     assert all(r["spans"][n] == {"count": 0, "s": 0.0} for n in SPANS)
     assert all(r["idle_by_span"][n] == 0 for n in SPANS)
+
+
+def test_mean_us():
+    r = _reduce(NEW)
+    for name in SPANS:
+        span = r["spans"][name]
+        assert spans.mean_us(r["spans"], name) == pytest.approx(
+            span["s"] / 4 * 1e6)
+    assert spans.mean_us(_reduce(TRACES[0])["spans"], SPANS[0]) is None
+    assert spans.mean_us(None, SPANS[0]) is None
 
 
 def test_subtract():

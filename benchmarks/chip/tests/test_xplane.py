@@ -4,6 +4,7 @@ from pathlib import Path
 
 import pytest
 
+import spans
 import xplane
 from runners.closed_loop import SPANS
 
@@ -11,8 +12,13 @@ TRACE = str(Path(__file__).parent / "data" / "lenet5-f32.sync.xplane.pb")
 
 
 @pytest.fixture(scope="module")
-def reduced():
-    return xplane.reduce(TRACE, n_layers=2, span_names=SPANS)
+def events():
+    return xplane.events(TRACE)
+
+
+@pytest.fixture(scope="module")
+def reduced(events):
+    return xplane.reduce(events, n_layers=2)
 
 
 def test_op_names_keep_the_instruction_name():
@@ -33,8 +39,8 @@ def test_every_request_and_layer_is_found(reduced):
     assert len(kernels) == 2
 
 
-def test_layers_follow_the_order_inside_each_execution():
-    devices, host = xplane._events(TRACE)
+def test_layers_follow_the_order_inside_each_execution(events):
+    devices, host = events
     (lines,) = devices.values()
     modules = sorted((s, e) for n, s, e in lines["XLA Modules"]
                      if xplane.NETWORK_MODULE in n)
@@ -46,10 +52,10 @@ def test_layers_follow_the_order_inside_each_execution():
                           "conv2d_offload_planned.3"]
 
 
-def test_device_clock_is_aligned_to_the_launches():
-    devices, host = xplane._events(TRACE)
+def test_device_clock_is_aligned_to_the_launches(events):
+    devices, host = events
     (lines,) = devices.values()
-    (lo, hi), = [(s, e) for n, s, e in host if n == xplane.WINDOW_SPAN]
+    lo, hi = xplane.window(host)
     launches = sorted(e for n, s, e in host
                       if n == xplane.LAUNCH and lo <= s < hi)
     starts = sorted(s for n, s, _ in lines["XLA Modules"]
@@ -63,13 +69,15 @@ def test_device_clock_is_aligned_to_the_launches():
     assert min(gaps) == 0 and all(g >= 0 for g in gaps)
 
 
-def test_busy_idle_and_gaps_add_up(reduced):
+def test_busy_idle_and_gaps_add_up(events, reduced):
     assert 0 < reduced["busy_s"] < reduced["window_s"]
     assert reduced["conv_s"] <= reduced["busy_s"]
-    idle = sum(s for _, s in reduced["idle_gaps"])
-    assert idle == pytest.approx(reduced["window_s"] - reduced["busy_s"],
-                                 rel=1e-6)
-    assert {n for n, _ in reduced["idle_gaps"]} <= set(SPANS) | {"other"}
+    gaps = spans.reduce(events, program_spans=(),
+                        span_names=SPANS)["idle_by_span"]
+    assert sum(gaps.values()) == pytest.approx(
+        reduced["window_s"] - reduced["busy_s"], rel=1e-6)
+    assert set(gaps) == set(SPANS) | {"other"}
+    assert gaps["request.wait"] > 0
     assert len(reduced["device_ops"]) <= xplane.TOP
 
 

@@ -50,8 +50,9 @@ def op_name(event_name: str) -> str:
     return event_name.split(" = ", 1)[0].lstrip("%")
 
 
-def _events(path: str):
-    """(device lines by chip, host events) as plain tuples.
+def events(path: str):
+    """The trace at ``path`` as plain tuples: (device lines by chip, host
+    events), read once and handed to ``reduce`` and ``spans.reduce``.
 
     Device lines map a chip's plane name to {line name: [(name, start_ns,
     end_ns)]}; host events are [(name, start_ns, end_ns)] over all host
@@ -114,9 +115,18 @@ def clock_shift(modules, launches) -> float:
     return min(s - e for s, e in zip(starts, launches))
 
 
-def reduce(path: str, *, n_layers: int, span_names=()) -> dict:
-    """The traced window's device time, conv kernel time per layer and
-    per image, and where the device sat idle.
+def window(host) -> tuple[int, int]:
+    """(start, end) of the one ``harness.window`` span among ``host``."""
+    windows = [(s, e) for name, s, e in host if name == WINDOW_SPAN]
+    if len(windows) != 1:
+        raise ValueError(f"expected one {WINDOW_SPAN} span, found "
+                         f"{len(windows)}")
+    return windows[0]
+
+
+def reduce(trace, *, n_layers: int) -> dict:
+    """The traced window's device time, and its conv kernel time per
+    layer and per image, from ``events``' ``trace``.
 
     ``window_s`` is the length of the ``harness.window`` span, and only
     device work inside it counts.  ``busy_s`` is the union of the
@@ -125,15 +135,9 @@ def reduce(path: str, *, n_layers: int, span_names=()) -> dict:
     ``conv_s`` their conv kernels' summed time and ``layer_s`` that time
     by layer (None where an execution did not hold exactly
     ``n_layers`` kernels).  ``device_ops`` lists the operations that
-    took most time, and ``idle_gaps`` the device's idle time by the host
-    span (among ``span_names``) that covered it, ``other`` where none
-    did."""
-    devices, host = _events(path)
-    windows = [(s, e) for name, s, e in host if name == WINDOW_SPAN]
-    if len(windows) != 1:
-        raise ValueError(f"expected one {WINDOW_SPAN} span, found "
-                         f"{len(windows)}")
-    lo, hi = windows[0]
+    took most time.  Where the device sat idle is ``spans.reduce``'s."""
+    devices, host = trace
+    lo, hi = window(host)
     launches = sorted(e for n, s, e in host if n == LAUNCH and lo <= s < hi)
     busy_ns = 0.0
     conv_ns = 0.0
@@ -141,10 +145,6 @@ def reduce(path: str, *, n_layers: int, span_names=()) -> dict:
     layer_ns = [0.0] * n_layers
     mapped = True
     op_ns: collections.Counter = collections.Counter()
-    idle_ns: collections.Counter = collections.Counter()
-    spans = {name: _union((s, e) for n, s, e in host if n == name)
-             for name in span_names}
-    ends = {name: [e for _, e in merged] for name, merged in spans.items()}
     for lines in devices.values():
         shift = clock_shift(lines.get("XLA Modules", ()), launches)
         lines = {name: [(n, s - shift, e - shift) for n, s, e in events]
@@ -174,21 +174,6 @@ def reduce(path: str, *, n_layers: int, span_names=()) -> dict:
                     layer_ns[k] += d
             else:
                 mapped = False
-        gaps = []
-        cursor = lo
-        for s, e in busy:
-            if s > cursor:
-                gaps.append((cursor, s))
-            cursor = max(cursor, e)
-        if hi > cursor:
-            gaps.append((cursor, hi))
-        for g0, g1 in gaps:
-            left = g1 - g0
-            for name, covered in spans.items():
-                part = _overlap(g0, g1, covered, ends[name])
-                idle_ns[name] += part
-                left -= part
-            idle_ns["other"] += max(left, 0.0)
     chips = max(len(devices), 1)
     return {
         "window_s": (hi - lo) * 1e-9,
@@ -198,6 +183,4 @@ def reduce(path: str, *, n_layers: int, span_names=()) -> dict:
         "layer_s": ([ns * 1e-9 for ns in layer_ns]
                     if mapped and images else None),
         "device_ops": [[n, ns * 1e-9] for n, ns in op_ns.most_common(TOP)],
-        "idle_gaps": [[n, ns * 1e-9] for n, ns in
-                      idle_ns.most_common(TOP) if ns > 0],
     }
