@@ -26,7 +26,8 @@ Rule families (see README for the full table):
 ``dur/floor``          claimed duration beats the analytic roofline /
                        communication floor — a cost-model bug
 ``reuse/*``            inter-layer reuse: savings exceed measured traffic,
-                       producer/consumer flags unpaired, bad row window
+                       producer/consumer flags unpaired, bad row window,
+                       any reuse in a plan whose graph is not a chain
 ``shard/*``            multi-chip geometry: bands / kernel ranges must
                        tile the layer, hybrid grids must match the
                        topology, halo windows must stay in bounds,
@@ -71,7 +72,8 @@ from repro.core.cost_model import HardwareModel
 from repro.core.formalism import (MemoryState, Step, StepError, apply_step,
                                   check_compute_feasible)
 from repro.core.network_planner import (LayerPlan, NetworkPlan,
-                                        _held_elements, _window_load_saved)
+                                        _held_elements, _window_load_saved,
+                                        is_chain)
 from repro.core.strategies import GroupedStrategy, k_min
 from repro.core.strategies_s2 import S2Strategy, s2_lower_bound
 
@@ -392,6 +394,7 @@ def verify_network_plan(plan: NetworkPlan) -> VerificationReport:
     the plan-level reuse pairing and duration recomposition."""
     report = VerificationReport(subject=f"network:{plan.name}")
     hw = plan.hw
+    dag = plan.graph is not None and not is_chain(plan.graph)
     for i, lp in enumerate(plan.layers):
         # a row-window cascade retains the consumer's window while the
         # producer still executes (the window is a copy: the producer
@@ -417,6 +420,12 @@ def verify_network_plan(plan: NetworkPlan) -> VerificationReport:
             report.add(Diagnostic.make(
                 "reuse/pairing", Severity.ERROR,
                 "first layer cannot reuse an upstream activation",
+                layer=lp.index))
+        if dag and (lp.reuse_input or lp.reuse_output or lp.window_rows):
+            report.add(Diagnostic.make(
+                "reuse/graph", Severity.ERROR,
+                "inter-layer reuse in a graph with skips or joins: the "
+                "pairing rules assume each layer reads the one before it",
                 layer=lp.index))
 
     total = sum(lp.duration for lp in plan.layers)

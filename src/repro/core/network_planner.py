@@ -141,8 +141,36 @@ class LayerPlan:
 
 
 @dataclasses.dataclass(frozen=True)
+class Node:
+    """Where one conv layer of a network graph reads, and what follows
+    its conv.
+
+    The conv reads the output of layer ``source`` (-1: the network's
+    input), pooled by ``pool`` (``"max2x2"``, ``"max3x3s2p1"`` or
+    ``"avg_global"``) and then zero-padded by ``pad``, ((top, bottom),
+    (left, right)).  Layer ``add``'s output is added to the conv's, and
+    ReLU applied where ``relu``; the result is this layer's output."""
+
+    source: int
+    pool: str | None = None
+    pad: tuple[tuple[int, int], tuple[int, int]] = ((0, 0), (0, 0))
+    add: int | None = None
+    relu: bool = False
+
+
+def is_chain(graph: Sequence[Node]) -> bool:
+    """Every layer reads the one before it, and nothing joins."""
+    return all(node.source == k - 1 and node.add is None
+               for k, node in enumerate(graph))
+
+
+@dataclasses.dataclass(frozen=True)
 class NetworkPlan:
-    """A solved whole-network offloading schedule."""
+    """A solved whole-network offloading schedule.
+
+    ``graph`` holds one :class:`Node` per layer; None is a chain, each
+    layer reading the previous one through the executor's inferred glue
+    (``kernels.emit.chain_graph``)."""
 
     name: str
     hw: HardwareModel
@@ -153,6 +181,7 @@ class NetworkPlan:
     planning_seconds: float
     solver_calls: int
     cache_hits: int
+    graph: tuple[Node, ...] | None = None
 
     @property
     def n_layers(self) -> int:
@@ -445,6 +474,7 @@ def plan_network(specs: Sequence[ConvSpec], hw: HardwareModel,
                  allow_reuse: bool = True,
                  solve_fn: Callable[..., solver_mod.SolveResult] | None = None,
                  verify: bool | None = None,
+                 graph: Sequence[Node] | None = None,
                  ) -> NetworkPlan:
     """Solve every layer and assemble the network schedule.
 
@@ -458,10 +488,25 @@ def plan_network(specs: Sequence[ConvSpec], hw: HardwareModel,
     ``verify=True`` runs the static plan verifier
     (``repro.analysis.verifier``) as a postcondition and raises
     ``PlanVerificationError`` on any error-severity diagnostic; the
-    default ``None`` defers to the ``REPRO_VERIFY_PLANS`` env knob."""
+    default ``None`` defers to the ``REPRO_VERIFY_PLANS`` env knob.
+
+    ``graph`` (one :class:`Node` per layer) is kept on the plan.
+    Inter-layer reuse pairs each layer with the next in file order, so a
+    graph that is not a chain plans only with ``allow_reuse=False``:
+    every activation then goes through HBM and each layer is planned
+    alone."""
     specs = list(specs)
     if not specs:
         raise ValueError("empty network")
+    if graph is not None:
+        graph = tuple(graph)
+        if len(graph) != len(specs):
+            raise ValueError(f"{len(graph)} graph nodes for {len(specs)} "
+                             f"layers")
+        if allow_reuse and not is_chain(graph):
+            raise ValueError("inter-layer reuse pairs neighbours in file "
+                             "order; a graph with skips or joins plans "
+                             "with allow_reuse=False")
     ps = _resolve_ps(specs, hw, p, max_group)
     fn = solve_fn or solver_mod.solve_cached
 
@@ -579,7 +624,7 @@ def plan_network(specs: Sequence[ConvSpec], hw: HardwareModel,
         total_duration=total, gross_duration=gross_total,
         baseline_duration=baseline,
         planning_seconds=planning_seconds,
-        solver_calls=solver_calls, cache_hits=cache_hits)
+        solver_calls=solver_calls, cache_hits=cache_hits, graph=graph)
     # lazy import: repro.analysis depends on this module
     from repro.analysis.verifier import assert_verified, should_verify
     if should_verify(verify):

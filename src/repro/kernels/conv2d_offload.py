@@ -234,13 +234,18 @@ def conv2d_offload(x: jax.Array, w: jax.Array, *,
 # a multiple of 128 lanes in f32, and to two rows of lanes in bf16 (a
 # packed bf16 tile pairs two rows; with one row per pixel it would pair
 # neighbouring pixels, and a window could not start at an odd column).
-# The input is (H, W, *pixel) in HBM, which Mosaic addresses one lane row
-# at a time, so a DMA cuts H and W at any offset.  The resident window is
-# (h_k, t_in, *pixel), the delta buffers are (h_k, nw, *pixel) and
-# (s_h, t_in, *pixel) (see ``_dma_buffer``), Λ is (h_k, w_k, *pixel,
-# C_out) and each step writes one (t_run, C_out) output block.  Every
-# VMEM slice below is static; only the HBM-side DMA offsets depend on the
-# grid index.
+# Mosaic cuts an HBM array at any offset only along dims whose minor dim
+# is one 128-lane tile, so an f32 pixel of ``ph = L / 128`` lane tiles
+# lies as ``ph`` consecutive rows of 128 lanes: the input is (H, W * ph,
+# 128) in HBM, pixel column w taking rows [w * ph, (w + 1) * ph), and
+# every column offset and count below is scaled by ``ph`` (1 for up to
+# 128 channels, where the layout is (H, W, 128)).  bf16 keeps (H, W,
+# *pixel) with ``ph`` 1.  The resident window is (h_k, t_in * ph,
+# *tail), the delta buffers are (h_k, nw * ph, *tail) and (s_h, t_in *
+# ph, *tail) (see ``_dma_buffer``), Λ is (h_k, w_k, *pixel, C_out) and
+# each step writes one (t_run, C_out) output block.  Every VMEM slice
+# below is static; only the HBM-side DMA offsets depend on the grid
+# index.
 
 def pixel_shape(c: int, dtype) -> tuple[int, ...]:
     """How the planned kernel stores one pixel's ``c`` channels: ``(L,)``
@@ -296,7 +301,7 @@ _VMEM_HEADROOM_BYTES = 1 << 20
 
 
 def _tap_dots(win_buf, w_ref, o_ref, *, t_run: int, s_w: int, h_k: int,
-              w_k: int, rows_used: int, precision):
+              w_k: int, rows_used: int, ph: int, precision):
     """One (t_run, L) @ (L, C_out) MXU dot per kernel tap (and per pixel
     row that holds channels), each over a static slice of the resident
     window, accumulated in f32."""
@@ -305,8 +310,13 @@ def _tap_dots(win_buf, w_ref, o_ref, *, t_run: int, s_w: int, h_k: int,
         for kw in range(w_k):
             cols = pl.ds(kw, t_run) if s_w == 1 else \
                 pl.ds(kw, t_run, stride=s_w)
-            if win_buf.ndim == 3:
+            if win_buf.ndim == 3 and ph == 1:
                 pairs = [(win_buf[kh, cols, :], w_ref[kh, kw])]
+            elif win_buf.ndim == 3:
+                # row r of each of the t_run pixels: every ph-th row
+                pairs = [(win_buf[kh, pl.ds(kw * ph + r, t_run,
+                                            stride=s_w * ph), :],
+                          w_ref[kh, kw, r]) for r in range(ph)]
             else:
                 pairs = [(win_buf[kh, cols, r, :], w_ref[kh, kw, r])
                          for r in range(rows_used)]
@@ -321,16 +331,19 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
                          sems, *,
                          t_run: int, s_h: int, s_w: int, h_k: int,
                          w_k: int, h_out: int, w_out_tiles: int,
-                         zigzag: bool, rows_used: int, precision):
+                         zigzag: bool, rows_used: int, ph: int, precision):
     """One plan step: retire the prefetched delta, update the resident
-    window, prefetch the next step's delta, then the per-tap MXU dots."""
+    window, prefetch the next step's delta, then the per-tap MXU dots.
+    Column offsets and counts are in rows of the (H, W * ph, ...) layout
+    (module note), so a pixel column is ``ph`` of them."""
     i = pl.program_id(0)
     jt_raw = pl.program_id(1)
     tiles = w_out_tiles
     jt = eff_tile(i, jt_raw, tiles, zigzag)
-    t_in = t_in_cols(t_run, s_w, w_k)
-    nw = t_run * s_w                    # new columns per within-row move
-    ov_w = t_in - nw                    # columns shared with the neighbour
+    t_in_px = t_in_cols(t_run, s_w, w_k)
+    nw_px = t_run * s_w                 # new columns per within-row move
+    ov_px = t_in_px - nw_px             # columns shared with the neighbour
+    t_in, nw, ov_w = t_in_px * ph, nw_px * ph, ov_px * ph
     keep_rows = h_k - s_h               # rows shared across a row turn
     row_delta = (zigzag or tiles == 1) and keep_rows > 0
     col_delta = ov_w > 0
@@ -412,7 +425,7 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
                 col_buf.at[:, :nw], sems.at[SEM_COL]).start()
 
     _tap_dots(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w, h_k=h_k,
-              w_k=w_k, rows_used=rows_used, precision=precision)
+              w_k=w_k, rows_used=rows_used, ph=ph, precision=precision)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -449,13 +462,18 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     precision = (jax.lax.Precision.HIGHEST if dt == jnp.float32
                  else jax.lax.Precision.DEFAULT)
     pix = pixel_shape(c_in, dt)
+    # an f32 pixel of more than one lane tile lies as ph rows of 128 lanes
+    ph = pix[0] // 128 if len(pix) == 1 else 1
+    tail = pix if ph == 1 else (128,)
     x = _to_pixels(x, 2, pix)
-    w = _to_pixels(w, 2, pix)
+    w = _to_pixels(w, 2, pix if ph == 1 else (ph, 128))
+    if ph > 1:
+        x = x.reshape(h_in, w_in * ph, 128)
     interpret = resolve_interpret(interpret)
     if not interpret:
         # XLA would keep a small input in VMEM; the kernel DMAs from HBM.
         x = pltpu.with_memory_space_constraint(x, pltpu.HBM)
-    scratch = [_dma_buffer(s, pix) for s in (
+    scratch = [_dma_buffer((rows, cols * ph), tail) for rows, cols in (
         (h_k, t_in),                                         # resident window
         (h_k, nw),                                           # column delta
         (max(1, min(s_h, h_k)), t_in),                       # row delta
@@ -468,7 +486,7 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     kernel = functools.partial(
         _conv_planned_kernel, t_run=t_run, s_h=s_h, s_w=s_w, h_k=h_k,
         w_k=w_k, h_out=h_out, w_out_tiles=w_out_tiles, zigzag=zig,
-        rows_used=-(-c_in // pix[-1]), precision=precision)
+        rows_used=-(-c_in // pix[-1]), ph=ph, precision=precision)
     out = pl.pallas_call(
         kernel,
         grid=(h_out, w_out_tiles),

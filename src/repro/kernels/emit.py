@@ -23,14 +23,29 @@ refuses anything it cannot map *exactly*:
 The emitted kernel implements the layer's *gross* schedule (every input
 pixel from HBM, every output written back); inter-layer reuse savings
 are a schedule-level accounting on top and do not change the kernel.
+One departure is emitted all the same: where the stride exceeds the
+kernel (a 1x1/2 projection), a step fetches the whole span of its run,
+the skipped columns included, where the plan's I_slice holds only the
+pixels its patches read; ``kerncheck`` reports the difference.
+
+:func:`plan_layers` plans a network given as a list of layer dicts: the
+shape keys of a ``ConvSpec`` and the graph keys of :data:`GRAPH_KEYS`
+(which earlier layer a conv reads, a pool and zero padding on the way
+in, a residual add and a ReLU on the way out), read into one
+:class:`~repro.core.network_planner.Node` a layer.  A layer of shape
+keys alone reads the previous one through the chain's glue, as
+:func:`plan_emitable_network` plans run.
 
 :func:`execute_network` runs a whole emitted plan as one jitted program
-on the kernels' (H, W, C) layout, with :func:`glue` (2x2 max-pool where
-the next layer's input is smaller, then zero padding) between layers;
-:func:`reference_network` is the plain f32 chain it is checked against.
+on the kernels' (H, W, C) layout, each conv reading the tensor its node
+names; a plan without a graph is the chain of :func:`chain_graph` (2x2
+max-pool where the next layer's input is smaller, then centred zero
+padding, as :func:`glue` adapts one tensor).  :func:`reference_network`
+is the plain f32 chain it is checked against.
 A plan is emitted once per plan object: the first call with a plan
-runs :func:`emit_layer_kernel` for each layer and keeps the result,
-keyed by the plan's identity (never its content, whose hash walks every
+runs :func:`emit_layer_kernel` for each layer and keeps the result with
+the graph, as the jitted program's one static argument (its hash taken
+then, not on each call), keyed by the plan's identity (never its content, whose hash walks every
 group), and every later call with the same object reads it back.  The
 entry holds only a weak reference to the plan and is dropped when the
 plan is freed; a plan that emission refuses is not kept and raises on
@@ -39,7 +54,8 @@ lookup) and the launch, as :data:`SPANS` into a JAX profiler trace when
 one is recording.  In ``repro.obs.metrics.REGISTRY`` each call counts
 one of ``executor/emit_hits`` and ``executor/emit_misses`` (the calls
 that ran emission), and each trace of the jitted program counts
-``executor/traces``.
+``executor/traces``, its residual adds ``executor/joins`` and the pools
+it emits ``executor/pools``.
 """
 from __future__ import annotations
 
@@ -50,10 +66,12 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.conv_spec import ConvSpec
 from repro.core.cost_model import HardwareModel
-from repro.core.network_planner import LayerPlan, NetworkPlan, plan_network
+from repro.core.network_planner import (
+    LayerPlan, NetworkPlan, Node, plan_network)
 from repro.core.solver import SolveResult
 from repro.core.strategies import (
     GridMeta, GroupedStrategy, lower_bound, zigzag)
@@ -67,8 +85,18 @@ from repro.kernels.conv2d_offload import conv2d_offload_planned, t_in_cols
 SPANS = ("executor.emit", "executor.launch")
 
 
+#: A layer dict's keys that make its ``ConvSpec``.
+SHAPE_KEYS = tuple(f.name for f in dataclasses.fields(ConvSpec))
+#: A layer dict's graph keys (see :func:`read_graph`).
+GRAPH_KEYS = ("input", "pool", "pad", "add", "relu")
+
+
 class KernelEmitError(ValueError):
     """The plan cannot be mapped onto an implemented kernel."""
+
+
+class GraphError(ValueError):
+    """A layer list whose graph the executor cannot run."""
 
 
 def kernel_vmem_elements(spec: ConvSpec, t_run: int) -> int:
@@ -224,9 +252,190 @@ def plan_emitable_network(specs, hw: HardwareModel, *, name: str,
                         solve_fn=grid_solve, **kwargs)
 
 
+def plan_layers(layers: Sequence[dict], hw: HardwareModel, *, name: str,
+                verify: bool = True) -> NetworkPlan:
+    """Plan a network given as layer dicts, in file order.
+
+    Each dict holds a ``ConvSpec``'s shape keys and any of
+    :data:`GRAPH_KEYS` (:func:`read_graph`).  Every conv is planned by
+    :func:`grid_solve` under ``hw`` with inter-layer reuse off, so each
+    layer is planned alone and its activation goes through HBM; the
+    plan keeps the graph, one ``plan.layers`` entry a dict.  A list of
+    shape keys alone plans as :func:`plan_emitable_network` does on the
+    same specs and runs the same program.  Refusals are ``ValueError``
+    subclasses: :class:`GraphError` for the graph, the planner's own
+    for a layer it cannot plan."""
+    with jax.profiler.TraceAnnotation("planner.plan_layers"):
+        specs, graph = read_graph(layers)
+        return plan_emitable_network(specs, hw, name=name, verify=verify,
+                                     graph=graph)
+
+
+# --------------------------------------------------------------------- #
+# Network graphs
+# --------------------------------------------------------------------- #
+
+def pooled_hw(pool: str, h: int, w: int) -> tuple[int, int]:
+    """(H, W) of an (h, w) map after ``pool``; GraphError where the pool
+    is unknown or the map cannot take it."""
+    if pool == "max2x2":
+        if h % 2 or w % 2:
+            raise GraphError(f"cannot 2x2-pool a {h}x{w} map")
+        return h // 2, w // 2
+    if pool == "max3x3s2p1":
+        return (h - 1) // 2 + 1, (w - 1) // 2 + 1
+    if pool == "avg_global":
+        return 1, 1
+    raise GraphError(f"unknown pool {pool!r}; known: max2x2, max3x3s2p1, "
+                     f"avg_global")
+
+
+def _chain_node(k: int, shape: tuple[int, int, int], spec: ConvSpec) -> Node:
+    """How :func:`glue` adapts an (H, W, C) ``shape`` to ``spec``'s
+    input, as the node of layer ``k`` reading layer ``k - 1``."""
+    h, w, c = shape
+    if c != spec.c_in:
+        raise KernelShapeError(
+            f"{c} channels feed a layer that expects {spec.c_in}")
+    pool = None
+    if h > spec.h_in or w > spec.w_in:
+        if h % 2 or w % 2:
+            raise KernelShapeError(f"cannot 2x2-pool a {h}x{w} map")
+        pool, h, w = "max2x2", h // 2, w // 2
+    ph, pw = spec.h_in - h, spec.w_in - w
+    if ph < 0 or pw < 0:
+        raise KernelShapeError(
+            f"a {h}x{w} map does not fit input {spec.h_in}x{spec.w_in}")
+    return Node(source=k - 1, pool=pool,
+                pad=((ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2)))
+
+
+def chain_graph(specs: Sequence[ConvSpec]) -> tuple[Node, ...]:
+    """The graph of a chain: each layer reads the one before it through
+    :func:`glue`, and the first reads the input as it is."""
+    nodes = [Node(source=-1)]
+    for k in range(1, len(specs)):
+        prev = specs[k - 1]
+        nodes.append(_chain_node(k, (prev.h_out, prev.w_out, prev.c_out),
+                                 specs[k]))
+    return tuple(nodes)
+
+
+def _index(k: int, layer: dict, key: str, default):
+    v = layer.get(key, default)
+    if isinstance(v, bool) or not isinstance(v, int):
+        raise GraphError(f"layer {k}: {key!r} must be a layer index, "
+                         f"not {v!r}")
+    return v
+
+
+def read_graph(layers: Sequence[dict]
+               ) -> tuple[list[ConvSpec], tuple[Node, ...]]:
+    """The specs and graph of a layer list; GraphError where it cannot
+    run.
+
+    Graph keys of layer k, each optional:
+
+    * ``input``: the layer whose output the conv reads, -1 for the
+      network's input (default: k - 1).  It must be an earlier layer.
+    * ``pool``: ``"max2x2"``, ``"max3x3s2p1"`` (3x3 window, stride 2,
+      one row and column of -inf on each side) or ``"avg_global"``,
+      applied to that tensor.
+    * ``pad``: zero rows and columns added on each side after pooling.
+    * ``add``: an earlier layer whose output is added to the conv's; the
+      shapes must match.
+    * ``relu``: apply ReLU after the add.
+
+    A layer with any of them must get, after pool and pad, exactly its
+    own input shape.  A layer with shape keys alone reads layer k - 1
+    through the chain's glue (:func:`glue`).  Layer 0 reads the
+    network's input as it is: its ``c_in, h_in, w_in`` are the input's
+    shape.  Any other key is refused."""
+    if not layers:
+        raise GraphError("no layers")
+    specs: list[ConvSpec] = []
+    nodes: list[Node] = []
+    shapes: list[tuple[int, int, int]] = []     # each layer's output
+    for k, layer in enumerate(layers):
+        unknown = sorted(set(layer) - set(SHAPE_KEYS) - set(GRAPH_KEYS))
+        if unknown:
+            raise GraphError(f"layer {k}: unknown keys {unknown}")
+        missing = [key for key in SHAPE_KEYS if key not in layer]
+        if missing:
+            raise GraphError(f"layer {k}: missing shape keys {missing}")
+        spec = ConvSpec(**{key: layer[key] for key in SHAPE_KEYS})
+        if k == 0:
+            image = (spec.h_in, spec.w_in, spec.c_in)
+        if not set(layer) & set(GRAPH_KEYS):
+            node = (Node(source=-1) if k == 0
+                    else _chain_node(k, shapes[-1], spec))
+        else:
+            node = _graph_node(k, layer, spec, shapes, image)
+        specs.append(spec)
+        nodes.append(node)
+        shapes.append((spec.h_out, spec.w_out, spec.c_out))
+    return specs, tuple(nodes)
+
+
+def _graph_node(k: int, layer: dict, spec: ConvSpec,
+                shapes: Sequence[tuple[int, int, int]],
+                image: tuple[int, int, int]) -> Node:
+    """Layer ``k``'s node from its graph keys (see :func:`read_graph`)."""
+    source = _index(k, layer, "input", k - 1)
+    if not -1 <= source < k:
+        raise GraphError(f"layer {k} reads layer {source}, which is not "
+                         f"an earlier layer")
+    pool = layer.get("pool")
+    pad = layer.get("pad", 0)
+    if isinstance(pad, bool) or not isinstance(pad, int) or pad < 0:
+        raise GraphError(f"layer {k}: pad must be a count of rows, not "
+                         f"{pad!r}")
+    relu = layer.get("relu", False)
+    if not isinstance(relu, bool):
+        raise GraphError(f"layer {k}: relu must be true or false")
+    if k == 0 and (source != -1 or pool is not None or pad):
+        raise GraphError("layer 0 reads the network's input as it is: "
+                         "no other input, pool or pad")
+    h, w, c = image if source < 0 else shapes[source]
+    if pool is not None:
+        h, w = pooled_hw(pool, h, w)
+    if c != spec.c_in:
+        raise GraphError(f"layer {k}: {c} channels feed a layer that "
+                         f"expects {spec.c_in}")
+    if (h + 2 * pad, w + 2 * pad) != (spec.h_in, spec.w_in):
+        raise GraphError(
+            f"layer {k}: a {h}x{w} map padded by {pad} is not its "
+            f"{spec.h_in}x{spec.w_in} input")
+    add = None
+    if "add" in layer:
+        add = _index(k, layer, "add", None)
+        if not 0 <= add < k:
+            raise GraphError(f"layer {k} adds layer {add}, which is not an "
+                             f"earlier layer")
+        out = (spec.h_out, spec.w_out, spec.c_out)
+        if shapes[add] != out:
+            raise GraphError(f"layer {k}: adds layer {add}'s "
+                             f"{shapes[add]} to its own {out} output")
+    return Node(source=source, pool=pool, pad=((pad, pad), (pad, pad)),
+                add=add, relu=relu)
+
+
 # --------------------------------------------------------------------- #
 # Whole-network execution
 # --------------------------------------------------------------------- #
+
+def apply_pool(y: jax.Array, kind: str) -> jax.Array:
+    """``y`` (H, W, C) pooled by ``kind`` (:func:`pooled_hw`)."""
+    h, w, c = y.shape
+    if kind == "max2x2":
+        return y.reshape(h // 2, 2, w // 2, 2, c).max(axis=(1, 3))
+    if kind == "max3x3s2p1":
+        return lax.reduce_window(y, -jnp.inf, lax.max, (3, 3, 1), (2, 2, 1),
+                                 ((1, 1), (1, 1), (0, 0)))
+    if kind == "avg_global":
+        return jnp.mean(y, axis=(0, 1), keepdims=True)
+    raise GraphError(f"unknown pool {kind!r}")
+
 
 def glue(y: jax.Array, spec: ConvSpec) -> jax.Array:
     """Adapt layer output ``y`` (H, W, C) to the next layer's ``spec``.
@@ -235,21 +444,10 @@ def glue(y: jax.Array, spec: ConvSpec) -> jax.Array:
     is what they are on the executed path.  Where ``y`` is larger than
     the next input (a stride-2 stage), a 2x2 max-pool halves it; then it
     is zero-padded, centred, to ``spec.h_in`` x ``spec.w_in``."""
-    h, w, c = y.shape
-    if c != spec.c_in:
-        raise KernelShapeError(
-            f"{c} channels feed a layer that expects {spec.c_in}")
-    if h > spec.h_in or w > spec.w_in:
-        if h % 2 or w % 2:
-            raise KernelShapeError(f"cannot 2x2-pool a {h}x{w} map")
-        h, w = h // 2, w // 2
-        y = y.reshape(h, 2, w, 2, c).max(axis=(1, 3))
-    ph, pw = spec.h_in - h, spec.w_in - w
-    if ph < 0 or pw < 0:
-        raise KernelShapeError(
-            f"a {h}x{w} map does not fit input {spec.h_in}x{spec.w_in}")
-    return jnp.pad(y, ((ph // 2, ph - ph // 2), (pw // 2, pw - pw // 2),
-                       (0, 0)))
+    node = _chain_node(1, y.shape, spec)
+    if node.pool is not None:
+        y = apply_pool(y, node.pool)
+    return jnp.pad(y, (*node.pad, (0, 0)))
 
 
 def execute_network(plan: NetworkPlan, x: jax.Array,
@@ -269,23 +467,50 @@ def execute_network(plan: NetworkPlan, x: jax.Array,
     ``executor/emit_hits`` and ``executor/emit_misses`` in
     ``repro.obs.metrics.REGISTRY``."""
     with jax.profiler.TraceAnnotation("executor.emit"):
-        layers = _emitted_layers(plan)
-    if len(weights) != len(layers):
+        program = _emitted_program(plan)
+    if len(weights) != len(program.layers):
         raise KernelShapeError(
-            f"{len(weights)} weight tensors for {len(layers)} layers")
+            f"{len(weights)} weight tensors for {len(program.layers)} "
+            f"layers")
     with jax.profiler.TraceAnnotation("executor.launch"):
-        return _execute(x, tuple(weights), layers=layers,
+        return _execute(x, tuple(weights), program=program,
                         interpret=resolve_interpret(interpret))
 
 
-#: ``id(plan)`` -> (weak reference to the plan, its emitted layers), for
+class _Program:
+    """The static part of ``_execute``: a plan's emitted layers and graph.
+
+    JAX hashes a static argument on every call to find its compiled
+    program; this one's hash is taken once, when the plan is emitted,
+    rather than walking every layer and node on each call.  Programs of
+    equal content are equal, so equal plans share one compiled
+    program."""
+
+    __slots__ = ("layers", "graph", "_hash")
+
+    def __init__(self, layers: tuple[EmittedConv, ...],
+                 graph: tuple[Node, ...]):
+        self.layers = layers
+        self.graph = graph
+        self._hash = hash((layers, graph))
+
+    def __hash__(self) -> int:
+        return self._hash
+
+    def __eq__(self, other) -> bool:
+        return self is other or (isinstance(other, _Program)
+                                 and self.layers == other.layers
+                                 and self.graph == other.graph)
+
+
+#: ``id(plan)`` -> (weak reference to the plan, its emitted program), for
 #: every live plan :func:`execute_network` has emitted.
-_EMITTED: dict[int, tuple[weakref.ref, tuple[EmittedConv, ...]]] = {}
+_EMITTED: dict[int, tuple[weakref.ref, _Program]] = {}
 
 
-def _emitted_layers(plan: NetworkPlan) -> tuple[EmittedConv, ...]:
-    """``plan``'s emitted layers, emitting them on the first call with
-    this plan object."""
+def _emitted_program(plan: NetworkPlan) -> _Program:
+    """``plan``'s emitted layers and graph, emitting them on the first
+    call with this plan object."""
     # Lazy import, as in _execute.
     from repro.obs.metrics import REGISTRY
     key = id(plan)
@@ -295,30 +520,54 @@ def _emitted_layers(plan: NetworkPlan) -> tuple[EmittedConv, ...]:
         return entry[1]
     REGISTRY.incr("executor/emit_misses")
     layers = tuple(emit_layer_kernel(lp) for lp in plan.layers)
+    graph = (plan.graph if plan.graph is not None
+             else chain_graph([lp.spec for lp in plan.layers]))
 
     def forget(ref: weakref.ref) -> None:
         # The plan is being freed; its id may be reused after this.
         if _EMITTED.get(key, (None,))[0] is ref:
             del _EMITTED[key]
 
-    _EMITTED[key] = (weakref.ref(plan, forget), layers)
-    return layers
+    program = _Program(layers, graph)
+    _EMITTED[key] = (weakref.ref(plan, forget), program)
+    return program
 
 
-@functools.partial(jax.jit, static_argnames=("layers", "interpret"))
-def _execute(x, weights, *, layers: tuple[EmittedConv, ...],
-             interpret: bool) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("program", "interpret"))
+def _execute(x, weights, *, program: _Program, interpret: bool
+             ) -> jax.Array:
     # Runs only while JAX traces the program, i.e. on a jit-cache miss.
     # Lazy import: repro.obs imports this module (through kerncheck).
     from repro.obs.metrics import REGISTRY
     REGISTRY.incr("executor/traces")
-    h = jnp.transpose(x, (1, 2, 0))
-    for k, (layer, w) in enumerate(zip(layers, weights)):
-        if k:
-            h = glue(h, layer.spec)
+    image = jnp.transpose(x, (1, 2, 0))
+    outs: list[jax.Array] = []
+    pooled: dict[tuple[int, str], jax.Array] = {}
+    for k, (layer, node, w) in enumerate(zip(program.layers, program.graph,
+                                             weights)):
+        h = image if node.source < 0 else outs[node.source]
+        if node.pool is not None:
+            # one pool per tensor and kind, however many convs read it
+            if (node.source, node.pool) not in pooled:
+                pooled[node.source, node.pool] = apply_pool(h, node.pool)
+                REGISTRY.incr("executor/pools")
+            h = pooled[node.source, node.pool]
+        if k and node.source != k - 1:
+            # Pin the program order: a conv that does not read the one
+            # before it runs after it, so the k-th conv kernel on the
+            # device is layer k.
+            h, _ = lax.optimization_barrier((h, outs[-1]))
+        if node.pad != ((0, 0), (0, 0)):
+            h = jnp.pad(h, (*node.pad, (0, 0)))
         h = layer.run_hwc(h, jnp.transpose(w, (2, 3, 1, 0)),
                           interpret=interpret)
-    return jnp.transpose(h, (2, 0, 1))
+        if node.add is not None:
+            h = h + outs[node.add]
+            REGISTRY.incr("executor/joins")
+        if node.relu:
+            h = jnp.maximum(h, 0)
+        outs.append(h)
+    return jnp.transpose(outs[-1], (2, 0, 1))
 
 
 def reference_network(specs: Sequence[ConvSpec], x: jax.Array,

@@ -2,12 +2,16 @@
 
 Interpret mode cannot see what the chip's compiler refuses (unaligned
 blocks and slices, unsupported relayouts, an input left in VMEM), so
-these tests compile at the real ``resnet8`` and ``lenet5`` widths for a
-described ``v5e:2x2`` topology, with ``interpret=False``.  Nothing runs.
-The topology is described inside a fixture: only one process may load
-the TPU library, and only the worker that runs this file does.
+these tests compile at the real ``resnet8``, ``lenet5`` and ResNet-50
+widths for a described ``v5e:2x2`` topology, with ``interpret=False``.
+Nothing runs.  The topology is described inside a fixture: only one
+process may load the TPU library, and only the worker that runs this
+file does.
 """
 import functools
+import json
+import re
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -16,12 +20,16 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.analysis.kerncheck import network_budget
 from repro.configs.networks import NETWORKS
+from repro.core.cost_model import HardwareModel
 from repro.kernels.conv2d_offload import conv2d_offload_planned
 from repro.kernels.emit import (
-    emit_layer_kernel, execute_network, plan_emitable_network)
+    SHAPE_KEYS, emit_layer_kernel, execute_network, plan_emitable_network,
+    plan_layers)
 
 NETS = ("resnet8", "lenet5")
 DTYPES = ("float32", "bfloat16")
+RESNET50 = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
+                       / "chip" / "configs" / "resnet50-f32.json").read_text())
 
 
 @functools.cache
@@ -40,6 +48,23 @@ def _distinct_layers():
             if key not in seen:
                 seen.add(key)
                 out.append((name, lp.index))
+    return out
+
+
+@functools.cache
+def _resnet50_plan():
+    return plan_layers(RESNET50["layers"], HardwareModel(**RESNET50["budget"]),
+                       name="resnet50")
+
+
+def _resnet50_distinct():
+    """Index of the first layer of each distinct ResNet-50 conv shape."""
+    seen, out = set(), []
+    for k, layer in enumerate(RESNET50["layers"]):
+        key = tuple(layer[s] for s in SHAPE_KEYS)
+        if key not in seen:
+            seen.add(key)
+            out.append(k)
     return out
 
 
@@ -68,10 +93,7 @@ def _no_compile_cache():
     jax.config.update("jax_enable_compilation_cache", prev)
 
 
-@pytest.mark.parametrize("dtype", DTYPES)
-@pytest.mark.parametrize("name,index", _distinct_layers())
-def test_planned_conv_compiles_for_v5e(one_chip, name, index, dtype):
-    lp = _plan(name).layers[index]
+def _compile_layer(one_chip, lp, dtype):
     e, s = emit_layer_kernel(lp), lp.spec
     x = jax.ShapeDtypeStruct((s.h_in, s.w_in, s.c_in), dtype,
                              sharding=one_chip)
@@ -86,6 +108,19 @@ def test_planned_conv_compiles_for_v5e(one_chip, name, index, dtype):
     # its windows out of HBM and would misread an input placed in VMEM
     assert '"input_memory_space_colors":[{"operand_index":"0","color":"0"' \
         in hlo
+
+
+@pytest.mark.parametrize("dtype", DTYPES)
+@pytest.mark.parametrize("name,index", _distinct_layers())
+def test_planned_conv_compiles_for_v5e(one_chip, name, index, dtype):
+    _compile_layer(one_chip, _plan(name).layers[index], dtype)
+
+
+@pytest.mark.parametrize("index", _resnet50_distinct())
+def test_resnet50_conv_compiles_for_v5e(one_chip, index):
+    """The strided, 1x1, 7x7, wide and FC kernels: each distinct f32
+    conv shape of ResNet-50 v1.5."""
+    _compile_layer(one_chip, _resnet50_plan().layers[index], "float32")
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -104,3 +139,31 @@ def test_execute_network_compiles_for_v5e(one_chip, name, dtype):
     last = specs[-1]
     assert compiled.out_info.shape == (last.c_out, last.h_out, last.w_out)
     assert compiled.out_info.dtype == jnp.dtype(dtype)
+
+
+def test_resnet50_network_compiles_in_layer_order_for_v5e(one_chip):
+    """One program of 54 conv kernels, the joins, ReLUs and pools between
+    them, and its kernels scheduled in the file's layer order (the
+    projection shortcut before the first 1x1 that reads the same
+    tensor)."""
+    plan = _resnet50_plan()
+    specs = [lp.spec for lp in plan.layers]
+    s0 = specs[0]
+    x = jax.ShapeDtypeStruct((s0.c_in, s0.h_in, s0.w_in), "float32",
+                             sharding=one_chip)
+    ws = [jax.ShapeDtypeStruct((s.c_out, s.c_in, s.h_k, s.w_k), "float32",
+                               sharding=one_chip) for s in specs]
+    compiled = jax.jit(lambda x, ws: execute_network(
+        plan, x, ws, interpret=False)).lower(x, ws).compile()
+    assert compiled.out_info.shape == (1000, 1, 1)
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    kernels = re.findall(r"= f32\[([0-9,]+)\]\{[^}]*\} custom-call\("
+                         r"[^)]*\), custom_call_target=\"tpu_custom_call\"",
+                         entry)
+    expected = []
+    for lp in plan.layers:
+        e, s = emit_layer_kernel(lp), lp.spec
+        expected.append(f"{s.h_out},{s.w_out // e.t_run},{e.t_run},"
+                        f"{s.c_out}")
+    assert kernels == expected
