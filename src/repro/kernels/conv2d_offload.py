@@ -36,9 +36,10 @@ derive each kernel's static access trace):
   new rows at a zigzag row turn), *prefetched* one step ahead into a
   separate delta buffer so the copy overlaps the previous step's MXU
   work, then accumulates one (T, C_in) x (C_in, C_out) dot per kernel
-  tap.  Double-buffering is exactly the part that is easy to get subtly
-  wrong (a dropped wait, a prefetch aimed at the live window), which is
-  why ``kerncheck`` proves its DMA trace hazard-free and its per-step
+  tap, or per group of taps where a pixel's channels fill no more than
+  half of its 128 lanes.  Double-buffering is exactly the part that is
+  easy to get subtly wrong (a dropped wait, a prefetch aimed at the live
+  window), which is why ``kerncheck`` proves its DMA trace hazard-free and its per-step
   regions equal to the plan's I_slices before the kernel is trusted.
   It compiles for the TPU (``tests/test_tpu_compile.py``).
 
@@ -246,6 +247,13 @@ def conv2d_offload(x: jax.Array, w: jax.Array, *,
 # each step writes one (t_run, C_out) output block.  Every VMEM slice
 # below is static; only the HBM-side DMA offsets depend on the grid
 # index.
+#
+# Narrow pixels share dots.  A one-row 32-bit pixel of ``c_in <= 64``
+# channels leaves at least half its 128 lanes zero, so ``g = 128 //
+# c_in`` taps fit one dot: tap j of a group is lane-rotated by ``j *
+# c_in`` and the g slices are summed, which concatenates their channels
+# exactly, and Λ is packed the same way, (dots, 128, C_out)
+# (``tap_group``, ``pack_taps``).
 
 def pixel_shape(c: int, dtype) -> tuple[int, ...]:
     """How the planned kernel stores one pixel's ``c`` channels: ``(L,)``
@@ -266,6 +274,37 @@ def _to_pixels(a: jax.Array, axis: int, pix: tuple[int, ...]) -> jax.Array:
     widths[axis] = (0, size - a.shape[axis])
     a = jnp.pad(a, widths)
     return a.reshape(a.shape[:axis] + pix + a.shape[axis + 1:])
+
+
+def tap_group(c: int, dtype) -> int:
+    """How many kernel taps share one MXU dot for ``c`` input channels:
+    ``128 // c`` where a pixel is one row of 128 lanes of 32-bit words,
+    2 or more only at ``c <= 64``; 1 (a dot per tap) for wider pixels
+    and for bf16's two-row pixel."""
+    return 128 // c if pixel_shape(c, dtype) == (128,) else 1
+
+
+def dots_per_step(h_k: int, w_k: int, c: int, dtype) -> int:
+    """MXU dots one grid step issues: one per group of ``tap_group``
+    taps, else one per tap and per pixel row that holds channels."""
+    g = tap_group(c, dtype)
+    if g > 1:
+        return -(-(h_k * w_k) // g)
+    pix = pixel_shape(c, dtype)
+    rows = pix[0] // 128 if len(pix) == 1 else -(-c // pix[-1])
+    return h_k * w_k * rows
+
+
+def pack_taps(w: jax.Array, g: int) -> jax.Array:
+    """(h_k, w_k, c, n) kernels as the (dots, 128, n) Λ of tap groups:
+    tap ``t = kh * w_k + kw``, channel ``k`` lies in dot ``t // g`` at
+    lane ``(t % g) * c + k``; the lanes no tap uses are zero."""
+    h_k, w_k, c, n = w.shape
+    taps = h_k * w_k
+    dots = -(-taps // g)
+    w = jnp.pad(w.reshape(taps, c, n), ((0, dots * g - taps), (0, 0), (0, 0)))
+    return jnp.pad(w.reshape(dots, g * c, n),
+                   ((0, 0), (0, 128 - g * c), (0, 0)))
 
 
 def _padded_vmem_bytes(shape: tuple[int, ...], dtype) -> int:
@@ -300,30 +339,48 @@ def _dma_buffer(rows_cols: tuple[int, int], pix: tuple[int, ...]
 _VMEM_HEADROOM_BYTES = 1 << 20
 
 
-def _tap_dots(win_buf, w_ref, o_ref, *, t_run: int, s_w: int, h_k: int,
-              w_k: int, rows_used: int, ph: int, precision):
-    """One (t_run, L) @ (L, C_out) MXU dot per kernel tap (and per pixel
-    row that holds channels), each over a static slice of the resident
-    window, accumulated in f32."""
-    acc = None
+def _tap_operands(win_buf, w_ref, *, t_run: int, s_w: int, h_k: int,
+                  w_k: int, rows_used: int, ph: int, g: int, c_in: int):
+    """The (t_run, L) window slice and (L, C_out) Λ slice of each MXU dot
+    of a step, in order, each window slice static.  With ``g > 1`` a dot
+    takes g taps, tap j of the group lane-rotated by ``j * c_in`` onto
+    its share of Λ's lanes (module note); otherwise one dot per tap (and
+    per pixel row that holds channels)."""
+    def cols(kw):
+        return pl.ds(kw, t_run) if s_w == 1 else pl.ds(kw, t_run, stride=s_w)
+
+    if g > 1:
+        taps = [divmod(t, w_k) for t in range(h_k * w_k)]
+        for d in range(0, len(taps), g):
+            xs = None
+            for j, (kh, kw) in enumerate(taps[d:d + g]):
+                part = win_buf[kh, cols(kw), :]
+                if j:
+                    part = pltpu.roll(part, j * c_in, axis=1)
+                xs = part if xs is None else xs + part
+            yield xs, w_ref[d // g]
+        return
     for kh in range(h_k):
         for kw in range(w_k):
-            cols = pl.ds(kw, t_run) if s_w == 1 else \
-                pl.ds(kw, t_run, stride=s_w)
             if win_buf.ndim == 3 and ph == 1:
-                pairs = [(win_buf[kh, cols, :], w_ref[kh, kw])]
+                yield win_buf[kh, cols(kw), :], w_ref[kh, kw]
             elif win_buf.ndim == 3:
                 # row r of each of the t_run pixels: every ph-th row
-                pairs = [(win_buf[kh, pl.ds(kw * ph + r, t_run,
-                                            stride=s_w * ph), :],
-                          w_ref[kh, kw, r]) for r in range(ph)]
+                yield from [(win_buf[kh, pl.ds(kw * ph + r, t_run,
+                                               stride=s_w * ph), :],
+                             w_ref[kh, kw, r]) for r in range(ph)]
             else:
-                pairs = [(win_buf[kh, cols, r, :], w_ref[kh, kw, r])
-                         for r in range(rows_used)]
-            for xs, ws in pairs:
-                part = jnp.dot(xs, ws, preferred_element_type=jnp.float32,
-                               precision=precision)
-                acc = part if acc is None else acc + part
+                yield from [(win_buf[kh, cols(kw), r, :], w_ref[kh, kw, r])
+                            for r in range(rows_used)]
+
+
+def _tap_dots(win_buf, w_ref, o_ref, *, precision, **geometry):
+    """The step's MXU dots (``_tap_operands``), accumulated in f32."""
+    acc = None
+    for xs, ws in _tap_operands(win_buf, w_ref, **geometry):
+        part = jnp.dot(xs, ws, preferred_element_type=jnp.float32,
+                       precision=precision)
+        acc = part if acc is None else acc + part
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
@@ -331,9 +388,10 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
                          sems, *,
                          t_run: int, s_h: int, s_w: int, h_k: int,
                          w_k: int, h_out: int, w_out_tiles: int,
-                         zigzag: bool, rows_used: int, ph: int, precision):
+                         zigzag: bool, rows_used: int, ph: int, g: int,
+                         c_in: int, precision):
     """One plan step: retire the prefetched delta, update the resident
-    window, prefetch the next step's delta, then the per-tap MXU dots.
+    window, prefetch the next step's delta, then the MXU dots.
     Column offsets and counts are in rows of the (H, W * ph, ...) layout
     (module note), so a pixel column is ``ph`` of them."""
     i = pl.program_id(0)
@@ -425,7 +483,8 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
                 col_buf.at[:, :nw], sems.at[SEM_COL]).start()
 
     _tap_dots(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w, h_k=h_k,
-              w_k=w_k, rows_used=rows_used, ph=ph, precision=precision)
+              w_k=w_k, rows_used=rows_used, ph=ph, g=g, c_in=c_in,
+              precision=precision)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -448,7 +507,8 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     prefetched one step ahead.  ``kernels.emit`` maps ``LayerPlan``s
     here; ``repro.analysis.kerncheck`` proves the equivalence statically.
     f32 operands are dotted at full precision; bf16 stays bf16 with f32
-    accumulation.
+    accumulation.  A step issues ``dots_per_step`` dots: taps share a dot
+    where ``tap_group`` allows, with Λ as ``pack_taps`` lays it out.
     """
     if order not in ("zigzag", "row"):
         raise KernelShapeError(f"unknown grid order {order!r}")
@@ -465,8 +525,10 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     # an f32 pixel of more than one lane tile lies as ph rows of 128 lanes
     ph = pix[0] // 128 if len(pix) == 1 else 1
     tail = pix if ph == 1 else (128,)
+    g = tap_group(c_in, dt)
     x = _to_pixels(x, 2, pix)
-    w = _to_pixels(w, 2, pix if ph == 1 else (ph, 128))
+    w = (pack_taps(w, g) if g > 1
+         else _to_pixels(w, 2, pix if ph == 1 else (ph, 128)))
     if ph > 1:
         x = x.reshape(h_in, w_in * ph, 128)
     interpret = resolve_interpret(interpret)
@@ -486,7 +548,8 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     kernel = functools.partial(
         _conv_planned_kernel, t_run=t_run, s_h=s_h, s_w=s_w, h_k=h_k,
         w_k=w_k, h_out=h_out, w_out_tiles=w_out_tiles, zigzag=zig,
-        rows_used=-(-c_in // pix[-1]), ph=ph, precision=precision)
+        rows_used=-(-c_in // pix[-1]), ph=ph, g=g, c_in=c_in,
+        precision=precision)
     out = pl.pallas_call(
         kernel,
         grid=(h_out, w_out_tiles),

@@ -54,8 +54,10 @@ lookup) and the launch, as :data:`SPANS` into a JAX profiler trace when
 one is recording.  In ``repro.obs.metrics.REGISTRY`` each call counts
 one of ``executor/emit_hits`` and ``executor/emit_misses`` (the calls
 that ran emission), and each trace of the jitted program counts
-``executor/traces``, its residual adds ``executor/joins`` and the pools
-it emits ``executor/pools``.
+``executor/traces``, its residual adds ``executor/joins``, the pools
+it emits ``executor/pools``, its conv layers' kernel taps
+``executor/taps`` and the MXU dots a grid step of theirs issues
+``executor/tap_dots`` (fewer than the taps where taps share a dot).
 """
 from __future__ import annotations
 
@@ -76,7 +78,8 @@ from repro.core.solver import SolveResult
 from repro.core.strategies import (
     GridMeta, GroupedStrategy, lower_bound, zigzag)
 from repro.kernels import KernelShapeError, ref, resolve_interpret
-from repro.kernels.conv2d_offload import conv2d_offload_planned, t_in_cols
+from repro.kernels.conv2d_offload import (
+    conv2d_offload_planned, dots_per_step, t_in_cols)
 
 
 #: Host spans of :func:`execute_network`, in the order a call opens them:
@@ -559,6 +562,10 @@ def _execute(x, weights, *, program: _Program, interpret: bool
             h, _ = lax.optimization_barrier((h, outs[-1]))
         if node.pad != ((0, 0), (0, 0)):
             h = jnp.pad(h, (*node.pad, (0, 0)))
+        spec = layer.spec
+        REGISTRY.incr("executor/taps", spec.h_k * spec.w_k)
+        REGISTRY.incr("executor/tap_dots", dots_per_step(
+            spec.h_k, spec.w_k, spec.c_in, h.dtype))
         h = layer.run_hwc(h, jnp.transpose(w, (2, 3, 1, 0)),
                           interpret=interpret)
         if node.add is not None:

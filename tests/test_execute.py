@@ -7,6 +7,8 @@ dtypes; interpret mode is chosen by backend; the compile cache is placed
 from outside or in the checkout; and ``chip_smoke.py`` refuses to run
 anywhere but on a TPU.
 """
+import functools
+import itertools
 import os
 import subprocess
 import sys
@@ -23,8 +25,8 @@ from repro.configs.networks import NETWORKS
 from repro.core.conv_spec import ConvSpec
 from repro.kernels import KernelShapeError, ref, resolve_interpret
 from repro.kernels.conv2d_offload import (
-    CASE_COL, CASE_FULL, CASE_ROW, conv2d_offload_planned, grid_sequence,
-    pixel_shape, step_case)
+    CASE_COL, CASE_FULL, CASE_ROW, conv2d_offload_planned, dots_per_step,
+    grid_sequence, pack_taps, pixel_shape, step_case, tap_group)
 from repro.kernels.emit import (
     execute_network, glue, plan_emitable_network, reference_network)
 
@@ -129,6 +131,93 @@ def test_planned_conv_channel_tiling(c_in, dtype):
 ])
 def test_pixel_shape(c, dtype, shape):
     assert pixel_shape(c, dtype) == shape
+
+
+# Taps sharing a dot: every packed width (c_in <= 64) and two unpacked
+# ones, each under the four kernel sizes, with strides and orders
+# alternating so that every width meets each (stride, order) pair and
+# every kernel size both orders.
+TAP_CASES = [(c_in, k, 1 + (i + j) % 2, ("zigzag", "row")[(i + j // 2) % 2])
+             for i, c_in in enumerate((1, 3, 6, 16, 32, 64, 65, 128))
+             for j, k in enumerate((1, 3, 5, 7))]
+
+
+@pytest.mark.parametrize("c_in,k,s,order", TAP_CASES)
+def test_planned_conv_with_taps_sharing_dots(c_in, k, s, order):
+    """f32 layers whose taps share a dot (and the widths whose taps do
+    not) give the plain reference convolution to the benchmark's
+    ``max_rel_err`` limit."""
+    t, w_out, h_out, n = 4, 8, 3, 5
+    x = jnp.asarray(RNG.standard_normal(((h_out - 1) * s + k,
+                                         (w_out - 1) * s + k, c_in)),
+                    jnp.float32)
+    kern = jnp.asarray(RNG.standard_normal((k, k, c_in, n)), jnp.float32)
+    out = conv2d_offload_planned(x, kern, t_run=t, s_h=s, s_w=s,
+                                 order=order)
+    exp = jnp.transpose(ref.conv2d(jnp.transpose(x, (2, 0, 1)),
+                                   jnp.transpose(kern, (3, 2, 0, 1)), s, s),
+                        (1, 2, 0))
+    assert out.shape == exp.shape == (h_out, w_out, n)
+    assert (tap_group(c_in, jnp.float32) > 1) == (c_in <= 64)
+    err = float(jnp.max(jnp.abs(out - exp)) / jnp.max(jnp.abs(exp)))
+    assert err <= 2.5e-06
+
+
+@pytest.mark.parametrize("k,c_in", [(7, 3), (3, 64), (5, 6), (3, 16),
+                                    (1, 1), (5, 1)])
+def test_pack_taps_puts_each_tap_in_its_lanes(k, c_in):
+    """Tap (kh, kw) = t, channel c lands in dot t // g at lane
+    (t % g) * c_in + c, and every lane no tap uses is zero."""
+    g = tap_group(c_in, jnp.float32)
+    assert g == 128 // c_in >= 2
+    w = RNG.standard_normal((k, k, c_in, 4)).astype(np.float32)
+    packed = np.asarray(pack_taps(jnp.asarray(w), g))
+    assert packed.shape == (-(-k * k // g), 128, 4)
+    assert dots_per_step(k, k, c_in, jnp.float32) == packed.shape[0]
+    used = np.zeros(packed.shape[:2], bool)
+    for kh, kw, c in itertools.product(range(k), range(k), range(c_in)):
+        t = kh * k + kw
+        np.testing.assert_array_equal(packed[t // g, t % g * c_in + c],
+                                      w[kh, kw, c])
+        used[t // g, t % g * c_in + c] = True
+    assert not packed[~used].any()
+
+
+def _lambda_shape(c_in, dtype, k=3):
+    """Shape of the Λ operand ``conv2d_offload_planned`` hands its
+    kernel."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn.invars[1].aval.shape
+            for p in eqn.params.values():
+                sub = getattr(p, "jaxpr", p)
+                if hasattr(sub, "eqns") and (shape := find(sub)):
+                    return shape
+        return None
+
+    fn = functools.partial(conv2d_offload_planned, t_run=4)
+    return find(jax.make_jaxpr(fn)(
+        jax.ShapeDtypeStruct((k + 3, k + 3, c_in), dtype),
+        jax.ShapeDtypeStruct((k, k, c_in, 2), dtype)).jaxpr)
+
+
+@pytest.mark.parametrize("c_in,dtype,shape,dots", [
+    (3, "float32", (1, 128, 2), 1),          # packed: 9 taps in 1 dot
+    (64, "float32", (5, 128, 2), 5),         # packed: 2 taps a dot
+    (65, "float32", (3, 3, 128, 2), 9),
+    (128, "float32", (3, 3, 128, 2), 9),
+    (300, "float32", (3, 3, 3, 128, 2), 27),
+    (3, "bfloat16", (3, 3, 2, 128, 2), 9),
+    (64, "bfloat16", (3, 3, 2, 128, 2), 9),
+])
+def test_wide_and_bf16_pixels_keep_a_dot_per_tap(c_in, dtype, shape, dots):
+    """Only one-row f32 pixels of at most 64 channels pack their taps;
+    wider pixels and bf16 keep Λ as (h_k, w_k, *pixel, n), a dot per
+    tap and pixel row."""
+    assert (tap_group(c_in, dtype) > 1) == (len(shape) == 3)
+    assert _lambda_shape(c_in, dtype) == shape
+    assert dots_per_step(3, 3, c_in, dtype) == dots
 
 
 # --------------------------------------------------------------------- #

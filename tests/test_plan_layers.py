@@ -169,9 +169,9 @@ def test_a_graph_with_joins_plans_without_reuse():
     assert {d.rule for d in report.diagnostics} >= {"reuse/graph"}
 
 
-def _counted(plan, shapes):
+def _counted(plan, shapes,
+             keys=("executor/traces", "executor/joins", "executor/pools")):
     """What tracing ``plan``'s program counts."""
-    keys = ("executor/traces", "executor/joins", "executor/pools")
     jax.clear_caches()
     before = [REGISTRY.get(k) for k in keys]
     jax.eval_shape(lambda x, ws: execute_network(plan, x, ws), *shapes)
@@ -202,3 +202,29 @@ def test_resnet50_counts_its_joins_and_pools_and_plans_alone():
                    for lp in plan.layers)
     specs = [lp.spec for lp in plan.layers]
     assert _counted(plan, _shapes(specs)) == [1, 16, 2]
+
+
+def _plan_of(name):
+    if name in NETWORKS:
+        specs = list(NETWORKS[name])
+        return plan_emitable_network(specs, network_budget(specs), name=name)
+    cfg = json.loads(RESNET50.read_text())
+    layers = cfg["layers"][:1] if name == "resnet50-stem" else cfg["layers"]
+    return plan_layers(layers, HardwareModel(**cfg["budget"]), name=name)
+
+
+@pytest.mark.parametrize("name, taps, dots", [
+    ("resnet8", 63, 18), ("lenet5", 50, 3), ("resnet50-stem", 49, 2),
+    ("resnet50", 230, 434)])
+def test_the_executor_counts_taps_and_the_dots_they_take(name, taps, dots):
+    """Each trace counts the layers' kernel taps and the dots a grid step
+    issues: resnet8's 3x3s on 3, 16, 32 and 64 channels take 1, 2, 3 and
+    5 dots; lenet5's 5x5s on 1 and 6 channels 1 and 2; ResNet-50's 7x7
+    stem 2, its three 3x3s on 64 channels 5 each, and every layer wider
+    than 128 channels one dot per tap and lane tile."""
+    plan = _plan_of(name)
+    specs = [lp.spec for lp in plan.layers]
+    assert _counted(plan, _shapes(specs), keys=(
+        "executor/traces", "executor/taps", "executor/tap_dots")) == \
+        [1, taps, dots]
+    assert {"executor/taps", "executor/tap_dots"} <= set(REGISTRY.keys())

@@ -21,7 +21,8 @@ from jax.sharding import SingleDeviceSharding
 from repro.analysis.kerncheck import network_budget
 from repro.configs.networks import NETWORKS
 from repro.core.cost_model import HardwareModel
-from repro.kernels.conv2d_offload import conv2d_offload_planned
+from repro.kernels.conv2d_offload import (
+    conv2d_offload_planned, dots_per_step)
 from repro.kernels.emit import (
     SHAPE_KEYS, emit_layer_kernel, execute_network, plan_emitable_network,
     plan_layers)
@@ -108,6 +109,7 @@ def _compile_layer(one_chip, lp, dtype):
     # its windows out of HBM and would misread an input placed in VMEM
     assert '"input_memory_space_colors":[{"operand_index":"0","color":"0"' \
         in hlo
+    return hlo
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
@@ -121,6 +123,20 @@ def test_resnet50_conv_compiles_for_v5e(one_chip, index):
     """The strided, 1x1, 7x7, wide and FC kernels: each distinct f32
     conv shape of ResNet-50 v1.5."""
     _compile_layer(one_chip, _resnet50_plan().layers[index], "float32")
+
+
+@pytest.mark.parametrize("index,c_in,k,dots", [(0, 3, 7, 2), (3, 64, 3, 5)])
+def test_narrow_resnet50_convs_compile_with_taps_sharing_dots(
+        one_chip, index, c_in, k, dots):
+    """The 7x7/2 stem on 3 channels and a 3x3 on 64: the compiled
+    kernel takes Λ packed as (dots, 128, n), 42 and 2 taps a dot."""
+    lp = _resnet50_plan().layers[index]
+    s = lp.spec
+    assert (s.c_in, s.h_k, s.w_k) == (c_in, k, k)
+    assert dots_per_step(k, k, c_in, "float32") == dots
+    call = next(line for line in _compile_layer(one_chip, lp, "float32")
+                .splitlines() if "tpu_custom_call" in line)
+    assert f"f32[{dots},128,{s.c_out}]{{" in call
 
 
 @pytest.mark.parametrize("dtype", DTYPES)
