@@ -65,10 +65,6 @@ class ConvSpec:
         """MACs to compute one output value."""
         return self.c_in * self.h_k * self.w_k
 
-    @property
-    def macs_total(self) -> int:
-        return self.nb_op_value * self.c_out * self.num_patches
-
     # --- sizes in tensor elements (for memory-footprint accounting) -------
     @property
     def kernel_elements(self) -> int:

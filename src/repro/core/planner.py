@@ -11,22 +11,22 @@ compute) against an on-chip memory of size ``size_MEM`` with a PE of
     kept-for-later  = block revisiting (index_map unchanged between steps)
     delta (eq. 15)  = HBM bytes moved / bandwidth + step overheads
 
-For every perf-critical operator the planner enumerates candidate
+For the block GeMM and flash decode the planner enumerates candidate
 *rectangular* strategies (tile shapes x loop orders), prices each with the
 paper's duration model, and returns the argmin.  Both the paper-faithful
 additive duration (no compute/copy overlap) and the overlapped duration
 (max of roofline terms — what a double-buffered TPU kernel achieves) are
-reported; optimisation uses the overlapped one by default.
+reported; optimisation uses the overlapped one by default.  The
+convolution is not planned here: ``kernels.emit.grid_solve`` picks its
+run length, in Def-3 cycles under the plan's ``size_mem``, through
+``core.network_planner.plan_network``.
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
-import math
 
-from repro.core.conv_spec import ConvSpec
 from repro.core.cost_model import TPU_V5E, TpuChipModel
-from repro.core.strategies import tiled as tiled_strategy
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -175,45 +175,4 @@ def plan_decode_attention(seq_len: int, head_dim: int, q_rows: int,
         bkv *= 2
     if best is None:
         raise ValueError("no KV block fits VMEM")
-    return best
-
-
-# --------------------------------------------------------------------- #
-# Convolution (the paper's own operator): rectangular S1 strategies.
-# --------------------------------------------------------------------- #
-
-def plan_conv(spec: ConvSpec, dtype_bytes: int = 2,
-              chip: TpuChipModel = TPU_V5E,
-              vmem_fraction: float = 0.7,
-              max_run: int = 64) -> Plan:
-    """Pick the row-run length T for the Pallas conv kernel: each grid step
-    computes a (1 x T) run of output columns for all C_out channels, with
-    all kernels VMEM-resident (S1).  Cost = paper eq. 15 with halo-aware
-    I_slice; evaluated exactly via the strategy bitmasks."""
-    budget = int(chip.vmem_bytes * vmem_fraction)
-    flops = 2 * spec.macs_total
-    best: Plan | None = None
-    for t in range(1, min(max_run, spec.w_out) + 1):
-        t_in = (t - 1) * spec.s_w + spec.w_k
-        vmem = (spec.kernel_elements * dtype_bytes          # resident Λ
-                + 2 * spec.c_in * spec.h_k * t_in * dtype_bytes
-                + spec.c_out * t * 4)
-        if vmem > budget:
-            continue
-        strat = tiled_strategy(spec, t, tile=(1, t))
-        pixels = strat.pixels_loaded()
-        hbm = (pixels * spec.c_in + spec.kernel_elements
-               + spec.num_patches * spec.c_out) * dtype_bytes
-        steps = strat.n_steps
-        t_mem = hbm / chip.hbm_bw
-        t_cmp = flops / chip.peak_flops
-        cand = Plan(kind="conv2d", tiles={"t": t}, order="zigzag",
-                    steps=steps, hbm_bytes=hbm, flops=flops, vmem_bytes=vmem,
-                    duration_additive=t_mem + t_cmp,
-                    duration_overlapped=max(t_mem, t_cmp))
-        if best is None or (cand.duration_overlapped, cand.steps) < \
-                (best.duration_overlapped, best.steps):
-            best = cand
-    if best is None:
-        raise ValueError("conv does not fit VMEM at any run length")
     return best

@@ -11,39 +11,30 @@ Strategy S1, faithfully mapped to the TPU memory hierarchy:
     step DMAs its window (or the part of it not yet resident) into a VMEM
     scratch buffer with ``pltpu.make_async_copy`` — action a4.
   * **patch groups** — one step computes a row-run of T output columns for
-    *all* C_out channels (Property 1).  T comes from the planner (the
-    nb_patches_max analogue under the VMEM budget).  Grid order is zigzag
-    (paper Sec 7.2) or row-by-row.
+    *all* C_out channels (Property 1).  T comes from the planner,
+    ``kernels.emit.grid_solve`` (the nb_patches_max analogue under the
+    VMEM budget).  Grid order is zigzag (paper Sec 7.2) or row-by-row.
   * **W / write-back** — the step's output block leaves VMEM when the grid
     moves on — action a3.
 
-Two variants share the geometry helpers below (which
-``repro.analysis.kerncheck`` also evaluates on concrete grid indices to
-derive each kernel's static access trace):
+The geometry helpers below are shared with ``repro.analysis.kerncheck``,
+which evaluates them on concrete grid indices to derive the kernel's
+static access trace.
 
-* :func:`conv2d_offload` — the simple seed kernel on (C, H, W) arrays:
-  every step DMAs its *full* ``(C_in, H_K, t_in)`` window, blocks on the
-  copy, and runs an im2col-in-VMEM plus one MXU dot.  It re-fetches the
-  ``w_k - s_w`` columns (and, across rows, the ``h_k - s_h`` rows) shared
-  with the previous step — traffic the plan's Def-3 ``I_slice``
-  accounting does *not* charge.  It runs in interpret mode only: its
-  (C_out, 1, T) output block and in-kernel reshape do not compile for
-  the chip.
-* :func:`conv2d_offload_planned` — the plan-shaped kernel
-  ``kernels.emit`` maps ``LayerPlan``s onto, on (H, W, C) arrays with
-  channels on the 128-lane axis: the window stays resident in VMEM and
-  each step DMAs only its **I_slice delta** (new columns within a row,
-  new rows at a zigzag row turn), *prefetched* one step ahead into a
-  separate delta buffer so the copy overlaps the previous step's MXU
-  work, then accumulates one (T, C_in) x (C_in, C_out) dot per kernel
-  tap, or per group of taps where a pixel's channels fill no more than
-  half of its 128 lanes.  Double-buffering is exactly the part that is
-  easy to get subtly wrong (a dropped wait, a prefetch aimed at the live
-  window), which is why ``kerncheck`` proves its DMA trace hazard-free and its per-step
-  regions equal to the plan's I_slices before the kernel is trusted.
-  It compiles for the TPU (``tests/test_tpu_compile.py``).
-
-Off the TPU both run in interpret mode (``kernels.resolve_interpret``).
+:func:`conv2d_offload_planned` is the kernel ``kernels.emit`` maps
+``LayerPlan``s onto, on (H, W, C) arrays with channels on the 128-lane
+axis: the window stays resident in VMEM and each step DMAs only its
+**I_slice delta** (new columns within a row, new rows at a zigzag row
+turn), *prefetched* one step ahead into a separate delta buffer so the
+copy overlaps the previous step's MXU work, then accumulates one
+(T, C_in) x (C_in, C_out) dot per kernel tap, or per group of taps where
+a pixel's channels fill no more than half of its 128 lanes.
+Double-buffering is exactly the part that is easy to get subtly wrong (a
+dropped wait, a prefetch aimed at the live window), which is why
+``kerncheck`` proves its DMA trace hazard-free and its per-step regions
+equal to the plan's I_slices before the kernel is trusted.  It compiles
+for the TPU (``tests/test_tpu_compile.py``); off the TPU it runs in
+interpret mode (``kernels.resolve_interpret``).
 """
 from __future__ import annotations
 
@@ -120,51 +111,13 @@ def step_case(i: int, jt: int, *, t_run: int, s_h: int, s_w: int,
     return CASE_FULL
 
 
-# --------------------------------------------------------------------- #
-# Seed kernel: full window DMA every step
-# --------------------------------------------------------------------- #
-
-def _conv_kernel(x_hbm, w_ref, o_ref, win_buf, sem, *,
-                 t_run: int, s_h: int, s_w: int, h_k: int, w_k: int,
-                 w_out_tiles: int, zigzag: bool):
-    """One S1 step: DMA the input window, im2col in VMEM, one MXU dot."""
-    i = pl.program_id(0)            # output row
-    jt = eff_tile(i, pl.program_id(1), w_out_tiles, zigzag)
-    t_in = t_in_cols(t_run, s_w, w_k)
-
-    # a4: load I_slice — the (C_in, H_K, t_in) window — into VMEM.
-    cp = pltpu.make_async_copy(
-        x_hbm.at[:, pl.ds(i * s_h, h_k), pl.ds(jt * t_run * s_w, t_in)],
-        win_buf, sem)
-    cp.start()
-    cp.wait()
-
-    _im2col_dot(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w, w_k=w_k)
-
-
-def _im2col_dot(win_buf, w_ref, o_ref, *, t_run: int, s_w: int, w_k: int):
-    """im2col in VMEM then one MXU matmul against the resident kernels.
-
-    (f32 upcast: XLA:CPU interpret mode lacks a bf16 dot thunk; on TPU the
-    MXU consumes bf16 directly and this cast fuses away.)"""
-    win = win_buf[...]
-    cols = [win[:, :, t * s_w:t * s_w + w_k].reshape(-1)
-            for t in range(t_run)]
-    patches = jnp.stack(cols, axis=0)            # (T, C_in*Hk*Wk)
-    out = jnp.dot(patches.astype(jnp.float32),
-                  w_ref[...].astype(jnp.float32),
-                  preferred_element_type=jnp.float32)
-    # (T, C_out) -> output block (C_out, 1, T)
-    o_ref[...] = out.T[:, None, :].astype(o_ref.dtype)
-
-
 def _conv_geometry(x_shape: tuple[int, ...], w_shape: tuple[int, ...],
                    t_run: int, s_h: int, s_w: int
-                   ) -> tuple[int, int, int, int, int]:
-    """Validate (C_in, H_in, W_in) input and (N, C_in, H_K, W_K) kernel
-    shapes; return (n, h_k, w_k, h_out, w_out_tiles)."""
-    c_in, h_in, w_in = x_shape
-    n, c_in2, h_k, w_k = w_shape
+                   ) -> tuple[int, int]:
+    """Validate (H_in, W_in, C_in) input and (H_K, W_K, C_in, N) kernel
+    shapes; return (h_out, w_out_tiles)."""
+    h_in, w_in, c_in = x_shape
+    h_k, w_k, c_in2, _ = w_shape
     if c_in != c_in2:
         raise KernelShapeError(
             f"input has {c_in} channels but kernels expect {c_in2}")
@@ -176,54 +129,8 @@ def _conv_geometry(x_shape: tuple[int, ...], w_shape: tuple[int, ...],
     if t_run <= 0 or w_out % t_run != 0:
         raise KernelShapeError(
             f"t_run={t_run} must divide w_out={w_out} "
-            f"(ops.conv2d pads/chooses for you)")
-    return n, h_k, w_k, h_out, w_out // t_run
-
-
-def _out_index_map(w_out_tiles: int, zigzag: bool):
-    def out_index(i, jt):
-        return (0, i, eff_tile(i, jt, w_out_tiles, zigzag))
-    return out_index
-
-
-def conv2d_offload(x: jax.Array, w: jax.Array, *,
-                   t_run: int, s_h: int = 1, s_w: int = 1,
-                   order: str = "zigzag",
-                   interpret: bool | None = None) -> jax.Array:
-    """S1 Pallas convolution (full-window DMA per step).
-
-    Args:
-      x: input (C_in, H_in, W_in) — already padded (paper Remark 2).
-      w: kernels (N, C_in, H_K, W_K).
-      t_run: patches per step (row-run length); ``W_out % t_run == 0``
-        (``ops.conv2d`` pads/chooses for you).
-      order: "zigzag" (paper Sec 7.2) or "row" grid sweep.
-    """
-    c_in = x.shape[0]
-    n, h_k, w_k, h_out, w_out_tiles = _conv_geometry(
-        x.shape, w.shape, t_run, s_h, s_w)
-    t_in = t_in_cols(t_run, s_w, w_k)
-    w_mat = w.reshape(n, -1).T          # (C_in*Hk*Wk, N)
-
-    kernel = functools.partial(
-        _conv_kernel, t_run=t_run, s_h=s_h, s_w=s_w, h_k=h_k, w_k=w_k,
-        w_out_tiles=w_out_tiles, zigzag=(order == "zigzag"))
-    return pl.pallas_call(
-        kernel,
-        grid=(h_out, w_out_tiles),
-        in_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),               # x stays in HBM
-            pl.BlockSpec((c_in * h_k * w_k, n), lambda i, jt: (0, 0)),  # Λ
-        ],
-        out_specs=pl.BlockSpec((n, 1, t_run),
-                               _out_index_map(w_out_tiles,
-                                              order == "zigzag")),
-        out_shape=jax.ShapeDtypeStruct((n, h_out, w_out_tiles * t_run),
-                                       x.dtype),
-        scratch_shapes=[pltpu.VMEM((c_in, h_k, t_in), x.dtype),
-                        pltpu.SemaphoreType.DMA],
-        interpret=resolve_interpret(interpret),
-    )(x, w_mat)
+            f"(kernels.emit.grid_solve picks one that does)")
+    return h_out, w_out // t_run
 
 
 # --------------------------------------------------------------------- #
@@ -498,7 +405,12 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     Args:
       x: input (H_in, W_in, C_in) — already padded (paper Remark 2).
       w: kernels (H_K, W_K, C_in, N).
-      t_run, s_h, s_w, order: as for :func:`conv2d_offload`.
+      t_run: patches per grid step, the row-run of output columns one
+        step computes; it must divide ``W_out``
+        (``kernels.emit.grid_solve`` plans one that does).
+      s_h, s_w: the vertical and horizontal strides.
+      order: the grid sweep, "zigzag" (paper Sec 7.2: odd rows run right
+        to left) or "row" (every row left to right).
 
     Returns the (H_out, W_out, N) output.  The traffic contract: each
     grid step fetches exactly the pixels the corresponding
@@ -513,9 +425,8 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     if order not in ("zigzag", "row"):
         raise KernelShapeError(f"unknown grid order {order!r}")
     h_in, w_in, c_in = x.shape
-    h_k, w_k, c_w, n = w.shape
-    _, _, _, h_out, w_out_tiles = _conv_geometry(
-        (c_in, h_in, w_in), (n, c_w, h_k, w_k), t_run, s_h, s_w)
+    h_k, w_k, _, n = w.shape
+    h_out, w_out_tiles = _conv_geometry(x.shape, w.shape, t_run, s_h, s_w)
     t_in = t_in_cols(t_run, s_w, w_k)
     nw = t_run * s_w
     dt = x.dtype

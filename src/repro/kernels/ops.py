@@ -1,10 +1,11 @@
-"""Jit'd public wrappers around the Pallas kernels.
+"""Jit'd public wrappers around the block-GeMM and flash-decode kernels.
 
-Each wrapper: pads to kernel-friendly shapes, consults ``core.planner`` for
-the offloading schedule when the caller does not pin one, dispatches to the
-Pallas kernel (interpret mode off the TPU, see ``resolve_interpret``), and
+Each wrapper: pads to kernel-friendly shapes, consults ``core.planner``
+for the tiles when the caller does not pin them, dispatches to the Pallas
+kernel (interpret mode off the TPU, see ``resolve_interpret``), and
 unpads.  ``ref.py`` holds the oracles; tests sweep shapes/dtypes and
-assert_allclose kernel vs oracle.
+assert_allclose kernel vs oracle.  The conv kernel has no wrapper here:
+``kernels.emit`` plans and runs it.
 """
 from __future__ import annotations
 
@@ -16,7 +17,6 @@ import jax.numpy as jnp
 from repro.core import planner
 from repro.kernels import KernelShapeError
 from repro.kernels import block_matmul as _bm
-from repro.kernels import conv2d_offload as _conv
 from repro.kernels import flash_decode as _fd
 
 
@@ -27,27 +27,6 @@ def _pad_to(x: jax.Array, axis: int, mult: int) -> jax.Array:
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
-
-
-@functools.partial(jax.jit, static_argnames=("t_run", "s_h", "s_w", "order"))
-def conv2d(x: jax.Array, w: jax.Array, *, t_run: int | None = None,
-           s_h: int = 1, s_w: int = 1, order: str = "zigzag") -> jax.Array:
-    """S1 Pallas convolution; ``t_run=None`` asks the planner."""
-    c_in, h_in, w_in = x.shape
-    n, _, h_k, w_k = w.shape
-    w_out = (w_in - w_k) // s_w + 1
-    if t_run is None:
-        from repro.core.conv_spec import ConvSpec
-        spec = ConvSpec(c_in, h_in, w_in, n, h_k, w_k, s_h, s_w)
-        t_run = planner.plan_conv(spec, dtype_bytes=x.dtype.itemsize
-                                  ).tiles["t"]
-    # pad W_in so W_out divides by t_run (extra columns discarded after)
-    pad_cols = ((-w_out) % t_run) * s_w
-    if pad_cols:
-        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad_cols)))
-    out = _conv.conv2d_offload(x, w, t_run=t_run, s_h=s_h, s_w=s_w,
-                               order=order)
-    return out[:, :, :w_out]
 
 
 @functools.partial(jax.jit,

@@ -63,6 +63,19 @@ def test_grid_solve_respects_kernel_vmem_budget():
     assert wide.objective <= res.objective
 
 
+def test_grid_solve_prefers_runs_longer_than_one_patch():
+    """Grouping patches into runs beats the one-patch S1 baseline: with
+    room for every run up to p, the solver takes the longest run that
+    divides w_out (62 = 2 x 31: of the runs up to 16, 1 and 2)."""
+    from repro.core.cost_model import HardwareModel
+    spec = ConvSpec(3, 64, 64, 8, 3, 3)
+    hw = HardwareModel(nbop_pe=1 << 20)
+    res = grid_solve(spec, 16, hw)
+    meta = res.strategy.as_grid()
+    assert meta is not None and meta.t_run == 2
+    assert res.objective < zigzag(spec, 1).objective(hw)
+
+
 def test_grid_solve_raises_when_nothing_fits():
     from repro.core.cost_model import HardwareModel
     hw = HardwareModel(nbop_pe=1 << 20,

@@ -4,52 +4,12 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import planner
-from repro.core.conv_spec import ConvSpec
 from repro.kernels import KernelShapeError, ops, ref
 from repro.kernels import block_matmul as _bm
 from repro.kernels import conv2d_offload as _conv
 from repro.kernels import flash_decode as _fd
 
 RNG = np.random.default_rng(42)
-
-
-# --------------------------- conv2d_offload --------------------------- #
-
-@pytest.mark.parametrize("c_in,h,w,n,kh,kw,sh,sw,t_run", [
-    (1, 6, 6, 1, 3, 3, 1, 1, 2),
-    (3, 12, 14, 5, 3, 3, 1, 1, 4),
-    (2, 9, 11, 4, 2, 2, 1, 1, 5),
-    (4, 16, 16, 8, 5, 5, 1, 1, 4),
-    (2, 11, 13, 3, 3, 3, 2, 2, 3),
-    (1, 8, 8, 2, 1, 1, 1, 1, 8),
-])
-def test_conv_shapes(c_in, h, w, n, kh, kw, sh, sw, t_run):
-    x = RNG.standard_normal((c_in, h, w)).astype(np.float32)
-    k = RNG.standard_normal((n, c_in, kh, kw)).astype(np.float32)
-    out = ops.conv2d(x, k, t_run=t_run, s_h=sh, s_w=sw)
-    exp = ref.conv2d(jnp.asarray(x), jnp.asarray(k), sh, sw)
-    np.testing.assert_allclose(out, exp, rtol=1e-4, atol=1e-4)
-
-
-@pytest.mark.parametrize("order", ["zigzag", "row"])
-@pytest.mark.parametrize("dtype", [np.float32, jnp.bfloat16])
-def test_conv_orders_dtypes(order, dtype):
-    x = RNG.standard_normal((2, 10, 12)).astype(dtype)
-    k = RNG.standard_normal((3, 2, 3, 3)).astype(dtype)
-    out = ops.conv2d(x, k, t_run=5, order=order)
-    exp = ref.conv2d(jnp.asarray(x), jnp.asarray(k))
-    tol = 1e-4 if dtype == np.float32 else 5e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(exp, np.float32),
-                               rtol=tol, atol=tol)
-
-
-def test_conv_planner_t_run():
-    x = RNG.standard_normal((2, 10, 12)).astype(np.float32)
-    k = RNG.standard_normal((3, 2, 3, 3)).astype(np.float32)
-    out = ops.conv2d(x, k)          # planner chooses t_run
-    exp = ref.conv2d(jnp.asarray(x), jnp.asarray(k))
-    np.testing.assert_allclose(out, exp, rtol=1e-4, atol=1e-4)
 
 
 # ----------------------------- block_matmul --------------------------- #
@@ -132,13 +92,6 @@ def test_planner_decode_attention_is_memory_bound():
     assert 32768 % p.tiles["bkv"] == 0
 
 
-def test_planner_conv_prefers_wider_runs():
-    spec = ConvSpec(3, 64, 64, 8, 3, 3)
-    p = planner.plan_conv(spec, dtype_bytes=4)
-    assert p.tiles["t"] > 1                    # grouping beats S1-baseline
-    assert p.vmem_bytes <= planner.TPU_V5E.vmem_bytes
-
-
 def test_planner_duration_models_ordering():
     p = planner.plan_matmul(1024, 1024, 1024, dtype_bytes=2)
     assert p.duration_overlapped <= p.duration_additive
@@ -154,6 +107,11 @@ def test_planner_duration_models_ordering():
     (3, 12, 14, 4, 5, 3, 1, 2, 2),     # tall kernel, stride-2 columns
     (1, 8, 8, 2, 1, 1, 1, 1, 4),       # 1x1 kernel: full fetch per tile
     (2, 13, 11, 3, 3, 3, 3, 1, 9),     # s_h >= h_k: no row-to-row reuse
+    (1, 6, 6, 1, 3, 3, 1, 1, 2),       # one channel, one kernel
+    (3, 12, 14, 5, 3, 3, 1, 1, 4),
+    (2, 9, 11, 4, 2, 2, 1, 1, 5),      # even kernel
+    (4, 16, 16, 8, 5, 5, 1, 1, 4),     # 5x5: 25 taps in one tap group
+    (1, 8, 8, 2, 1, 1, 1, 1, 8),       # 1x1 kernel, one tile per row
 ])
 def test_conv_planned_delta_fetch_matches_ref(order, c_in, h, w, n, kh, kw,
                                               sh, sw, t_run):
@@ -170,12 +128,25 @@ def test_conv_planned_delta_fetch_matches_ref(order, c_in, h, w, n, kh, kw,
                                rtol=1e-4, atol=1e-4)
 
 
+@pytest.mark.parametrize("order", ["zigzag", "row"])
+def test_conv_planned_bf16_matches_ref(order):
+    """bf16 operands stay bf16 on the MXU with f32 accumulation."""
+    x = RNG.standard_normal((2, 10, 12)).astype(jnp.bfloat16)
+    k = RNG.standard_normal((3, 2, 3, 3)).astype(jnp.bfloat16)
+    out = _conv.conv2d_offload_planned(
+        jnp.asarray(x.transpose(1, 2, 0)), jnp.asarray(k.transpose(2, 3, 1, 0)),
+        t_run=5, order=order, interpret=True)
+    exp = ref.conv2d(jnp.asarray(x), jnp.asarray(k))
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.transpose(np.asarray(exp, np.float32),
+                                            (1, 2, 0)),
+                               rtol=5e-2, atol=5e-2)
+
+
 def test_kernel_geometry_errors_are_typed():
     """Bare asserts were replaced by KernelShapeError raises (lint L006
     now covers kernels/): bad geometry must raise the typed error, not
     AssertionError, and survive python -O."""
-    x = jnp.zeros((2, 8, 8), jnp.float32)
-    k = jnp.zeros((3, 2, 3, 3), jnp.float32)
     x_hwc = jnp.zeros((8, 8, 2), jnp.float32)
     k_hwc = jnp.zeros((3, 3, 2, 3), jnp.float32)
     with pytest.raises(KernelShapeError):
@@ -185,8 +156,9 @@ def test_kernel_geometry_errors_are_typed():
         _conv.conv2d_offload_planned(x_hwc, k_hwc, t_run=4, s_w=1, s_h=1,
                                      order="zigzag", interpret=True)
     with pytest.raises(KernelShapeError):      # channel mismatch
-        _conv.conv2d_offload(x, jnp.zeros((3, 1, 3, 3), jnp.float32),
-                             t_run=3, interpret=True)
+        _conv.conv2d_offload_planned(
+            x_hwc, jnp.zeros((3, 3, 1, 3), jnp.float32), t_run=3,
+            interpret=True)
     a = jnp.zeros((64, 64), jnp.float32)
     with pytest.raises(KernelShapeError):      # tiles must divide dims
         _bm.block_matmul(a, a, bm=48, bn=32, bk=32, order="mnk",

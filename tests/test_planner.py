@@ -11,7 +11,10 @@ from hypothesis import given, settings, strategies as st
 
 from repro.core import planner
 from repro.core.conv_spec import ConvSpec
-from repro.core.cost_model import TPU_V5E
+from repro.core.cost_model import TPU_V5E, HardwareModel
+from repro.core.network_planner import LayerPlan
+from repro.kernels.emit import (
+    emit_layer_kernel, grid_solve, kernel_vmem_elements)
 
 
 @settings(max_examples=25, deadline=None)
@@ -42,14 +45,27 @@ def test_property_decode_plan_invariants(s_log, d, g):
 
 @settings(max_examples=20, deadline=None)
 @given(hw_in=st.integers(8, 40), c_in=st.integers(1, 8),
-       n=st.integers(1, 16), kk=st.sampled_from([1, 3, 5]))
-def test_property_conv_plan_invariants(hw_in, c_in, n, kk):
+       n=st.integers(1, 16), kk=st.sampled_from([1, 3, 5]),
+       p=st.integers(1, 64), room=st.one_of(st.none(), st.floats(0, 1)))
+def test_property_grid_solve_invariants(hw_in, c_in, n, kk, p, room):
+    """The conv planner's choice is a run the emitted kernel realises,
+    fits the memory it is given, and prices no lower than the bound.
+    ``room`` places ``size_mem`` between what the one-patch run and the
+    whole-row run occupy (None: unbounded)."""
     hypothesis.assume(hw_in > kk)
     spec = ConvSpec(c_in, hw_in, hw_in, n, kk, kk)
-    p = planner.plan_conv(spec, dtype_bytes=2)
-    assert 1 <= p.tiles["t"] <= spec.w_out
-    assert p.vmem_bytes <= TPU_V5E.vmem_bytes
-    # bytes at least: unique input pixels + kernels + output, once each
-    lb = 2 * (spec.all_pixels_mask.bit_count() * c_in
-              + spec.kernel_elements + spec.num_patches * n)
-    assert p.hbm_bytes >= lb
+    least = kernel_vmem_elements(spec, 1)
+    size_mem = (None if room is None else least + int(
+        room * (kernel_vmem_elements(spec, spec.w_out) - least)))
+    hw = HardwareModel(nbop_pe=1 << 20, size_mem=size_mem)
+    res = grid_solve(spec, p, hw)
+    t_run = res.strategy.as_grid().t_run
+    assert spec.w_out % t_run == 0 and t_run <= p
+    if size_mem is not None:
+        assert kernel_vmem_elements(spec, t_run) <= size_mem
+    lp = LayerPlan(index=0, spec=spec, p=p, result=res, reuse_input=False,
+                   reuse_output=False, window_rows=0,
+                   gross_duration=res.objective, input_load_saved=0.0,
+                   write_back_saved=0.0)
+    assert emit_layer_kernel(lp).t_run == t_run
+    assert res.objective >= res.lower_bound

@@ -37,7 +37,7 @@ def test_metrics_match_formalism():
     assert rep.elements_read == (strat.pixels_loaded() * spec.c_in
                                  + spec.kernel_elements)
     assert rep.elements_written == spec.num_patches * spec.c_out
-    assert rep.total_macs == spec.macs_total
+    assert rep.total_macs == spec.nb_op_value * spec.c_out * spec.num_patches
 
 
 def test_capacity_overflow_detected():
