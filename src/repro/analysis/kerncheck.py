@@ -120,7 +120,7 @@ def build_conv_trace(emitted: EmittedConv) -> KernelTrace:
 
 def _conv_trace(spec: ConvSpec, t: int, order: str, *, name: str,
                 vmem_elements: int) -> KernelTrace:
-    c, hk, wk = spec.c_in, spec.h_k, spec.w_k
+    c, hk, wk = spec.in_channels, spec.h_k, spec.w_k
     sh, sw = spec.s_h, spec.s_w
     tiles = spec.w_out // t
     t_in = t_in_cols(t, sw, wk)
@@ -289,7 +289,7 @@ def check_conv_trace(trace: KernelTrace, strategy: GroupedStrategy,
             f"written, {len(bad)} written more than once",
             missing=missing, multi=len(bad))
 
-    want_traffic = (strategy.pixels_loaded() * spec.c_in
+    want_traffic = (strategy.pixels_loaded() * spec.in_channels
                     + spec.kernel_elements)
     if total_loaded != want_traffic:
         err("kern/traffic",

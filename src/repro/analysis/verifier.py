@@ -214,7 +214,7 @@ def walk_steps(spec: ConvSpec, hw: HardwareModel, steps: Sequence[Step],
             + s.k_sub.bit_count() * kelem * hw.t_l
         write = _mask_weight(s.w, wb_w) * hw.t_w
         durations.append(load + write + (hw.t_acc if s.computes else 0.0))
-        occupancies.append(m_next.inp.bit_count() * spec.c_in
+        occupancies.append(m_next.inp.bit_count() * spec.in_channels
                            + m_next.ker.bit_count() * kelem
                            + _mask_weight(m_next.out, fp_w))
         written_cum.append(written_elems)
@@ -385,7 +385,7 @@ def _held_in_elements(plan: NetworkPlan, i: int) -> int:
     if lp.reuse_input and i > 0:
         return _held_elements(plan.layers[i - 1].spec, lp.spec)
     if lp.window_rows:
-        return lp.window_rows * lp.spec.w_in * lp.spec.c_in
+        return lp.window_rows * lp.spec.w_in * lp.spec.in_channels
     return 0
 
 
@@ -403,7 +403,7 @@ def verify_network_plan(plan: NetworkPlan) -> VerificationReport:
         if i + 1 < len(plan.layers) and plan.layers[i + 1].window_rows:
             nxt_spec = plan.layers[i + 1].spec
             held_out = plan.layers[i + 1].window_rows \
-                * nxt_spec.w_in * nxt_spec.c_in
+                * nxt_spec.w_in * nxt_spec.in_channels
         _verify_layer_plan(report, lp, hw,
                            held_in=_held_in_elements(plan, i),
                            held_out=held_out)

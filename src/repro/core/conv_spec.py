@@ -1,7 +1,9 @@
 """Convolution slicing (paper Sec 3, Defs 4-11).
 
 A 2D convolution takes a 3D input ``(C_in, H_in, W_in)`` and N kernels
-``(C_in, H_K, W_K)`` and produces ``(N, H_out, W_out)``.  The *patch*
+``(C_in, H_K, W_K)`` and produces ``(N, H_out, W_out)``.  A depthwise
+conv (``groups == N``) takes an input of N channels and N kernels of
+one channel each: kernel n reads input channel n only.  The *patch*
 ``P_{i,j}`` is the input slice needed to compute output column ``O[:, i, j]``.
 
 Per the paper's Remark 6 we work with 2-D *spatial* pixels — the channel
@@ -21,7 +23,15 @@ import functools
 
 @dataclasses.dataclass(frozen=True)
 class ConvSpec:
-    """A convolution layer (already-padded input)."""
+    """A convolution layer (already-padded input).
+
+    ``c_in`` is the channels one kernel reads, its weight's second axis.
+    ``groups`` is the conv's channel groups, PyTorch's ``groups`` (not
+    the paper's kernel groups): 1 for a dense conv, or ``n_kernels``
+    with ``c_in == 1`` for a depthwise one, whose input holds
+    :attr:`in_channels` = ``c_in * groups`` channels.  Λ and the MACs of
+    an output value follow the kernel's depth (``c_in``); a pixel's
+    loads and footprint follow the input's (:attr:`in_channels`)."""
 
     c_in: int
     h_in: int
@@ -31,10 +41,22 @@ class ConvSpec:
     w_k: int
     s_h: int = 1
     s_w: int = 1
+    groups: int = 1
 
     def __post_init__(self):
         if self.h_out < 1 or self.w_out < 1:
             raise ValueError(f"kernel larger than input: {self}")
+        if self.groups != 1 and not (self.groups == self.n_kernels
+                                     and self.c_in == 1):
+            raise ValueError(
+                f"groups={self.groups}: only 1 (dense) or n_kernels="
+                f"{self.n_kernels} with c_in=1 (depthwise) is supported, "
+                f"not a general grouped conv of c_in={self.c_in}")
+
+    @property
+    def in_channels(self) -> int:
+        """Channels of the input tensor, and so of one input pixel."""
+        return self.c_in * self.groups
 
     # --- Def 8 ------------------------------------------------------------
     @property
@@ -56,7 +78,8 @@ class ConvSpec:
 
     @property
     def num_pixels(self) -> int:
-        """Spatial pixels of the input grid (Remark 6: channel collapsed)."""
+        """Spatial pixels of the input grid (Remark 6: channel collapsed;
+        a pixel holds :attr:`in_channels` elements)."""
         return self.h_in * self.w_in
 
     # --- Def 13 -----------------------------------------------------------
@@ -68,7 +91,8 @@ class ConvSpec:
     # --- sizes in tensor elements (for memory-footprint accounting) -------
     @property
     def kernel_elements(self) -> int:
-        """All kernels: C_out * C_in * H_K * W_K (term 2 of eq. 12)."""
+        """All kernels: C_out * C_in * H_K * W_K (term 2 of eq. 12), C_in
+        the kernel's depth."""
         return self.c_out * self.c_in * self.h_k * self.w_k
 
     # --- linearisation (Remarks 4-5) ---------------------------------------

@@ -43,7 +43,7 @@ class MemoryState:
 
     def footprint_elements(self, spec: ConvSpec) -> int:
         """Tensor elements resident (channels expanded)."""
-        return (self.inp.bit_count() * spec.c_in
+        return (self.inp.bit_count() * spec.in_channels
                 + self.ker.bit_count() * spec.c_in * spec.h_k * spec.w_k
                 + self.out.bit_count() * spec.c_out)
 

@@ -154,7 +154,7 @@ def build_ilp(spec: ConvSpec, p: int, k: int | None = None,
     if size_mem is not None:
         ker_elems = spec.kernel_elements
         for kk in range(k):
-            add([(model.g_col(jx, kk), float(spec.c_in))
+            add([(model.g_col(jx, kk), float(spec.in_channels))
                  for jx in range(j_count)]
                 + [(model.pg_col(i, kk), float(spec.c_out))
                    for i in range(x_count)],

@@ -860,6 +860,11 @@ def plan_multichip_network(specs: Sequence[ConvSpec], cluster: ClusterModel,
     specs = list(specs)
     if not specs:
         raise ValueError("empty network")
+    grouped = [i for i, s in enumerate(specs) if s.groups != 1]
+    if grouped:
+        raise NotImplementedError(
+            f"layers {grouped}: the multi-chip planner shards dense convs "
+            f"only, not groups={specs[grouped[0]].groups}")
     if same_pad and cluster.n_chips == 1:
         raise ValueError(
             "same_pad models the sharded planner's band accounting; the "

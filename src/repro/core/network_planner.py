@@ -147,15 +147,19 @@ class Node:
 
     The conv reads the output of layer ``source`` (-1: the network's
     input), pooled by ``pool`` (``"max2x2"``, ``"max3x3s2p1"`` or
-    ``"avg_global"``) and then zero-padded by ``pad``, ((top, bottom),
-    (left, right)).  Layer ``add``'s output is added to the conv's, and
-    ReLU applied where ``relu``; the result is this layer's output."""
+    ``"avg_global"``), layer-normed over channels where ``ln`` is
+    ``"in"``, and then zero-padded by ``pad``, ((top, bottom), (left,
+    right)).  Layer ``add``'s output is added to the conv's, then ReLU
+    applied where ``relu`` or GELU where ``gelu``, then a LayerNorm
+    where ``ln`` is ``"out"``; the result is this layer's output."""
 
     source: int
     pool: str | None = None
     pad: tuple[tuple[int, int], tuple[int, int]] = ((0, 0), (0, 0))
     add: int | None = None
     relu: bool = False
+    gelu: bool = False
+    ln: str | None = None
 
 
 def is_chain(graph: Sequence[Node]) -> bool:
@@ -230,7 +234,8 @@ class NetworkPlan:
             if lp.reuse_output:
                 tags.append("out->chip")
             lines.append(
-                f"  L{lp.index}: {lp.spec.c_in}x{lp.spec.h_in}x{lp.spec.w_in}"
+                f"  L{lp.index}: {lp.spec.in_channels}x{lp.spec.h_in}"
+                f"x{lp.spec.w_in}"
                 f" -> {lp.spec.c_out}x{lp.spec.h_out}x{lp.spec.w_out}"
                 f"  p={lp.p} steps={lp.strategy.n_steps}"
                 f" strat={lp.strategy.name}"
@@ -255,7 +260,8 @@ def _held_elements(prev: ConvSpec, nxt: ConvSpec) -> int:
     """Resident elements of a fully held activation: the larger of prev's
     output map and nxt's input map (pooling/padding between them happens
     on-chip)."""
-    return max(prev.num_patches * prev.c_out, nxt.num_pixels * nxt.c_in)
+    return max(prev.num_patches * prev.c_out,
+               nxt.num_pixels * nxt.in_channels)
 
 
 def activation_fits(prev: ConvSpec, prev_strategy: Strategy,
@@ -308,7 +314,7 @@ def row_window_rows(prev: ConvSpec, prev_strategy: Strategy,
     Returns 0 when no admissible window exists."""
     if hw.size_mem is None:
         return 0                      # full residency always fits
-    per_row = nxt.w_in * nxt.c_in
+    per_row = nxt.w_in * nxt.in_channels
     spare = hw.size_mem - max(
         prev_strategy.peak_footprint_elements() + producer_extra_held,
         nxt_strategy.peak_footprint_elements())
@@ -407,7 +413,7 @@ def _assemble_layers(specs: Sequence[ConvSpec], ps: Sequence[int],
             kind, rows = reuse_after[i - 1]
             held_in = (_held_elements(specs[i - 1], specs[i])
                        if kind == "full"
-                       else rows * specs[i].w_in * specs[i].c_in)
+                       else rows * specs[i].w_in * specs[i].in_channels)
         choice: tuple[str, int] | None = None
         if allow_reuse:
             if activation_fits(specs[i], results[i].strategy,
@@ -528,7 +534,7 @@ def plan_network(specs: Sequence[ConvSpec], hw: HardwareModel,
                    polish_restarts=polish_restarts))
         except ValueError as e:
             raise InfeasibleNetworkError(
-                f"layer {i} ({spec.c_in}x{spec.h_in}x{spec.w_in}"
+                f"layer {i} ({spec.in_channels}x{spec.h_in}x{spec.w_in}"
                 f"->{spec.c_out}): no strategy fits "
                 f"size_mem={hw.size_mem}") from e
     t_solved = time.perf_counter()
@@ -562,7 +568,8 @@ def plan_network(specs: Sequence[ConvSpec], hw: HardwareModel,
             caps = []
             if not layers[i].window_rows:
                 caps.append(hw.size_mem
-                            - specs[i].h_k * specs[i].w_in * specs[i].c_in)
+                            - specs[i].h_k * specs[i].w_in
+                            * specs[i].in_channels)
             caps.append(hw.size_mem - _held_elements(specs[i - 1],
                                                      specs[i]))
             peak_i = results[i].strategy.peak_footprint_elements()

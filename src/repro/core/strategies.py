@@ -183,7 +183,7 @@ class GroupedStrategy:
         and two groups' outputs (write-back happens at the *next* step, so
         the previous group's outputs coexist with the current one's)."""
         return (self.spec.kernel_elements
-                + self.peak_input_footprint() * self.spec.c_in
+                + self.peak_input_footprint() * self.spec.in_channels
                 + 2 * self.max_group_size() * self.spec.c_out)
 
     def peak_working_set_elements(self) -> int:
@@ -192,7 +192,7 @@ class GroupedStrategy:
         held map instead of draining through write-backs (the producer-side
         term of the network planner's reuse fit condition)."""
         return (self.spec.kernel_elements
-                + self.peak_input_footprint() * self.spec.c_in)
+                + self.peak_input_footprint() * self.spec.in_channels)
 
     def first_load_duration(self, hw: HardwareModel) -> float:
         """t_l traffic of first-time input-pixel loads — the most an

@@ -63,6 +63,15 @@ def _chunks(seq, n):
     return [tuple(seq[i:i + n]) for i in range(0, len(seq), n)]
 
 
+def _dense_only(spec: ConvSpec) -> None:
+    """S2 swaps groups of dense kernels; a depthwise kernel group would
+    read only its own channels, which no S2 step models."""
+    if spec.groups != 1:
+        raise NotImplementedError(
+            f"S2 kernel-group swapping plans dense convs only, not "
+            f"groups={spec.groups}")
+
+
 @dataclasses.dataclass(frozen=True)
 class S2Strategy:
     """Ordered (patch-group, kernel-group-index) schedule."""
@@ -73,6 +82,7 @@ class S2Strategy:
     schedule: tuple[tuple[tuple[int, ...], int], ...]   # ((patch ids), kg)
 
     def __post_init__(self):
+        _dense_only(self.spec)
         seen: set[tuple[int, int]] = set()
         for g, kg in self.schedule:
             for pid in g:
@@ -344,6 +354,7 @@ def enumerate_s2_seed(spec: ConvSpec, hw: HardwareModel,
     """Best (builder, p, kernel-group size) under the caps, priced closed
     form; only the winner is materialised.  Ragged final kernel groups are
     included — every kg size 1..N is admissible, not just divisors."""
+    _dense_only(spec)
     if kg_sizes is None:
         kg_sizes = range(1, spec.n_kernels + 1)
     profiles: dict[int, _ZigProfile] = {}

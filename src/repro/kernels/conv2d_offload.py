@@ -28,7 +28,11 @@ axis: the window stays resident in VMEM and each step DMAs only its
 turn), *prefetched* one step ahead into a separate delta buffer so the
 copy overlaps the previous step's MXU work, then accumulates one
 (T, C_in) x (C_in, C_out) dot per kernel tap, or per group of taps where
-a pixel's channels fill no more than half of its 128 lanes.
+a pixel's channels fill no more than half of its 128 lanes.  A depthwise
+layer (kernels of one channel, one per input channel) has no contraction
+over channels: the same grid, window and DMAs, but each step multiplies
+the window's (T, lanes) slice of each tap lane-wise by that tap's
+(1, lanes) weight row on the VPU and accumulates in f32.
 Double-buffering is exactly the part that is easy to get subtly wrong (a
 dropped wait, a prefetch aimed at the live window), which is why
 ``kerncheck`` proves its DMA trace hazard-free and its per-step regions
@@ -111,16 +115,26 @@ def step_case(i: int, jt: int, *, t_run: int, s_h: int, s_w: int,
     return CASE_FULL
 
 
+def is_depthwise(x_channels: int, w_shape: tuple[int, ...]) -> bool:
+    """Whether (H_K, W_K, C, N) kernels on an input of ``x_channels``
+    channels make a depthwise conv: kernels of one channel, one kernel a
+    channel, more than one channel (one channel is a dense conv)."""
+    _, _, c, n = w_shape
+    return c == 1 and n == x_channels > 1
+
+
 def _conv_geometry(x_shape: tuple[int, ...], w_shape: tuple[int, ...],
                    t_run: int, s_h: int, s_w: int
                    ) -> tuple[int, int]:
     """Validate (H_in, W_in, C_in) input and (H_K, W_K, C_in, N) kernel
-    shapes; return (h_out, w_out_tiles)."""
+    shapes, or depthwise (H_K, W_K, 1, C_in) kernels; return (h_out,
+    w_out_tiles)."""
     h_in, w_in, c_in = x_shape
     h_k, w_k, c_in2, _ = w_shape
-    if c_in != c_in2:
+    if c_in != c_in2 and not is_depthwise(c_in, w_shape):
         raise KernelShapeError(
-            f"input has {c_in} channels but kernels expect {c_in2}")
+            f"input has {c_in} channels but kernels expect {c_in2} "
+            f"(and are not one depthwise kernel a channel)")
     h_out = (h_in - h_k) // s_h + 1
     w_out = (w_in - w_k) // s_w + 1
     if h_out <= 0 or w_out <= 0:
@@ -246,6 +260,11 @@ def _dma_buffer(rows_cols: tuple[int, int], pix: tuple[int, ...]
 _VMEM_HEADROOM_BYTES = 1 << 20
 
 
+def _strided(start: int, n: int, stride: int):
+    """A static slice of ``n`` rows from ``start``, ``stride`` apart."""
+    return pl.ds(start, n) if stride == 1 else pl.ds(start, n, stride=stride)
+
+
 def _tap_operands(win_buf, w_ref, *, t_run: int, s_w: int, h_k: int,
                   w_k: int, rows_used: int, ph: int, g: int, c_in: int):
     """The (t_run, L) window slice and (L, C_out) Λ slice of each MXU dot
@@ -254,7 +273,7 @@ def _tap_operands(win_buf, w_ref, *, t_run: int, s_w: int, h_k: int,
     its share of Λ's lanes (module note); otherwise one dot per tap (and
     per pixel row that holds channels)."""
     def cols(kw):
-        return pl.ds(kw, t_run) if s_w == 1 else pl.ds(kw, t_run, stride=s_w)
+        return _strided(kw, t_run, s_w)
 
     if g > 1:
         taps = [divmod(t, w_k) for t in range(h_k * w_k)]
@@ -291,14 +310,40 @@ def _tap_dots(win_buf, w_ref, o_ref, *, precision, **geometry):
     o_ref[...] = acc.astype(o_ref.dtype)
 
 
+def _depthwise_taps(win_buf, w_ref, o_ref, *, t_run: int, s_w: int,
+                    h_k: int, w_k: int, rows_used: int, ph: int):
+    """The step's depthwise taps on the VPU, no MXU dot: for each lane
+    row r of a pixel (``ph`` rows of 128 lanes in f32, ``rows_used``
+    rows of L lanes in bf16), the sum over taps of the window's
+    (t_run, lanes) slice times the tap's (1, lanes) weight row, in f32,
+    written to output lanes [r * lanes, (r + 1) * lanes)."""
+    n = o_ref.shape[-1]
+    lanes = win_buf.shape[-1]
+    for r in range(ph if win_buf.ndim == 3 else rows_used):
+        acc = None
+        for kh in range(h_k):
+            for kw in range(w_k):
+                if win_buf.ndim == 3:
+                    xs = win_buf[kh, _strided(kw * ph + r, t_run, s_w * ph), :]
+                else:
+                    xs = win_buf[kh, _strided(kw, t_run, s_w), r, :]
+                part = (xs.astype(jnp.float32)
+                        * w_ref[kh, kw, pl.ds(r, 1), :].astype(jnp.float32))
+                acc = part if acc is None else acc + part
+        lo = r * lanes
+        width = min(lanes, n - lo)
+        o_ref[:, lo:lo + width] = acc[:, :width].astype(o_ref.dtype)
+
+
 def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
                          sems, *,
                          t_run: int, s_h: int, s_w: int, h_k: int,
                          w_k: int, h_out: int, w_out_tiles: int,
                          zigzag: bool, rows_used: int, ph: int, g: int,
-                         c_in: int, precision):
+                         c_in: int, depthwise: bool, precision):
     """One plan step: retire the prefetched delta, update the resident
-    window, prefetch the next step's delta, then the MXU dots.
+    window, prefetch the next step's delta, then the MXU dots (the VPU
+    taps where ``depthwise``).
     Column offsets and counts are in rows of the (H, W * ph, ...) layout
     (module note), so a pixel column is ``ph`` of them."""
     i = pl.program_id(0)
@@ -389,9 +434,13 @@ def _conv_planned_kernel(x_hbm, w_ref, o_ref, win_buf, col_buf, row_buf,
                 x_hbm.at[pl.ds(h0_n, h_k), pl.ds(w0_n + delta_off_n, nw)],
                 col_buf.at[:, :nw], sems.at[SEM_COL]).start()
 
-    _tap_dots(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w, h_k=h_k,
-              w_k=w_k, rows_used=rows_used, ph=ph, g=g, c_in=c_in,
-              precision=precision)
+    if depthwise:
+        _depthwise_taps(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w,
+                        h_k=h_k, w_k=w_k, rows_used=rows_used, ph=ph)
+    else:
+        _tap_dots(win_buf, w_ref, o_ref, t_run=t_run, s_w=s_w, h_k=h_k,
+                  w_k=w_k, rows_used=rows_used, ph=ph, g=g, c_in=c_in,
+                  precision=precision)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -404,7 +453,9 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
 
     Args:
       x: input (H_in, W_in, C_in) — already padded (paper Remark 2).
-      w: kernels (H_K, W_K, C_in, N).
+      w: kernels (H_K, W_K, C_in, N), or (H_K, W_K, 1, C_in) for a
+        depthwise conv (:func:`is_depthwise`), whose steps take their
+        taps on the VPU.
       t_run: patches per grid step, the row-run of output columns one
         step computes; it must divide ``W_out``
         (``kernels.emit.grid_solve`` plans one that does).
@@ -427,6 +478,7 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     h_in, w_in, c_in = x.shape
     h_k, w_k, _, n = w.shape
     h_out, w_out_tiles = _conv_geometry(x.shape, w.shape, t_run, s_h, s_w)
+    depthwise = is_depthwise(c_in, w.shape)
     t_in = t_in_cols(t_run, s_w, w_k)
     nw = t_run * s_w
     dt = x.dtype
@@ -436,10 +488,16 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
     # an f32 pixel of more than one lane tile lies as ph rows of 128 lanes
     ph = pix[0] // 128 if len(pix) == 1 else 1
     tail = pix if ph == 1 else (128,)
-    g = tap_group(c_in, dt)
+    g = 1 if depthwise else tap_group(c_in, dt)
     x = _to_pixels(x, 2, pix)
-    w = (pack_taps(w, g) if g > 1
-         else _to_pixels(w, 2, pix if ph == 1 else (ph, 128)))
+    if depthwise:
+        # Λ as one weight row of the pixel's lanes per tap and lane row
+        w = _to_pixels(w.reshape(h_k, w_k, n), 2,
+                       pix if len(pix) == 2 else (ph, 128))
+    elif g > 1:
+        w = pack_taps(w, g)
+    else:
+        w = _to_pixels(w, 2, pix if ph == 1 else (ph, 128))
     if ph > 1:
         x = x.reshape(h_in, w_in * ph, 128)
     interpret = resolve_interpret(interpret)
@@ -460,7 +518,7 @@ def conv2d_offload_planned(x: jax.Array, w: jax.Array, *,
         _conv_planned_kernel, t_run=t_run, s_h=s_h, s_w=s_w, h_k=h_k,
         w_k=w_k, h_out=h_out, w_out_tiles=w_out_tiles, zigzag=zig,
         rows_used=-(-c_in // pix[-1]), ph=ph, g=g, c_in=c_in,
-        precision=precision)
+        depthwise=depthwise, precision=precision)
     out = pl.pallas_call(
         kernel,
         grid=(h_out, w_out_tiles),

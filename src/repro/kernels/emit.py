@@ -29,9 +29,10 @@ the skipped columns included, where the plan's I_slice holds only the
 pixels its patches read; ``kerncheck`` reports the difference.
 
 :func:`plan_layers` plans a network given as a list of layer dicts: the
-shape keys of a ``ConvSpec`` and the graph keys of :data:`GRAPH_KEYS`
-(which earlier layer a conv reads, a pool and zero padding on the way
-in, a residual add and a ReLU on the way out), read into one
+keys of a ``ConvSpec`` (its channel ``groups`` optional) and the graph
+keys of :data:`GRAPH_KEYS` (which earlier layer a conv reads, a pool, a
+LayerNorm and zero padding on the way in; a residual add, a ReLU or a
+GELU and a LayerNorm on the way out), read into one
 :class:`~repro.core.network_planner.Node` a layer.  A layer of shape
 keys alone reads the previous one through the chain's glue, as
 :func:`plan_emitable_network` plans run.
@@ -56,8 +57,12 @@ one of ``executor/emit_hits`` and ``executor/emit_misses`` (the calls
 that ran emission), and each trace of the jitted program counts
 ``executor/traces``, its residual adds ``executor/joins``, the pools
 it emits ``executor/pools``, its conv layers' kernel taps
-``executor/taps`` and the MXU dots a grid step of theirs issues
-``executor/tap_dots`` (fewer than the taps where taps share a dot).
+``executor/taps``, the MXU dots a grid step of the dense ones issues
+``executor/tap_dots`` (fewer than the taps where taps share a dot), its
+depthwise layers ``executor/depthwise``, its LayerNorms
+``executor/layernorms`` and its GELUs ``executor/gelus``.  The
+LayerNorm and GELU ops sit under the named scopes
+``executor.layernorm`` and ``executor.gelu``.
 """
 from __future__ import annotations
 
@@ -80,6 +85,7 @@ from repro.core.strategies import (
 from repro.kernels import KernelShapeError, ref, resolve_interpret
 from repro.kernels.conv2d_offload import (
     conv2d_offload_planned, dots_per_step, t_in_cols)
+from repro.obs.metrics import REGISTRY
 
 
 #: Host spans of :func:`execute_network`, in the order a call opens them:
@@ -88,10 +94,14 @@ from repro.kernels.conv2d_offload import (
 SPANS = ("executor.emit", "executor.launch")
 
 
-#: A layer dict's keys that make its ``ConvSpec``.
-SHAPE_KEYS = tuple(f.name for f in dataclasses.fields(ConvSpec))
+#: A layer dict's keys that every layer holds; with the optional
+#: ``groups`` (1 where absent) they make its ``ConvSpec``.
+SHAPE_KEYS = tuple(f.name for f in dataclasses.fields(ConvSpec)
+                   if f.name != "groups")
 #: A layer dict's graph keys (see :func:`read_graph`).
-GRAPH_KEYS = ("input", "pool", "pad", "add", "relu")
+GRAPH_KEYS = ("input", "pool", "pad", "add", "relu", "gelu", "ln")
+#: LayerNorm's epsilon, as ConvNeXt's (Liu et al. 2022) LayerNorms have it.
+LN_EPS = 1e-6
 
 
 class KernelEmitError(ValueError):
@@ -116,9 +126,10 @@ def kernel_vmem_elements(spec: ConvSpec, t_run: int) -> int:
     t_in = t_in_cols(t_run, spec.s_w, spec.w_k)
     nw = t_run * spec.s_w
     lam = spec.kernel_elements
-    win = spec.c_in * spec.h_k * t_in
-    col = spec.c_in * spec.h_k * nw
-    row = spec.c_in * max(1, min(spec.s_h, spec.h_k)) * t_in
+    c = spec.in_channels
+    win = c * spec.h_k * t_in
+    col = c * spec.h_k * nw
+    row = c * max(1, min(spec.s_h, spec.h_k)) * t_in
     out2 = 2 * spec.c_out * t_run
     return lam + win + col + row + out2
 
@@ -154,10 +165,10 @@ class EmittedConv:
         """Execute the plan on the kernel's layout: x (H_in, W_in, C_in),
         w (Hk, Wk, C_in, N) -> (H_out, W_out, N)."""
         spec = self.spec
-        if x.shape != (spec.h_in, spec.w_in, spec.c_in):
+        if x.shape != (spec.h_in, spec.w_in, spec.in_channels):
             raise KernelShapeError(
                 f"layer {self.layer_index}: input {x.shape} != plan spec "
-                f"({spec.h_in}, {spec.w_in}, {spec.c_in})")
+                f"({spec.h_in}, {spec.w_in}, {spec.in_channels})")
         if w.shape != (spec.h_k, spec.w_k, spec.c_in, spec.c_out):
             raise KernelShapeError(
                 f"layer {self.layer_index}: kernels {w.shape} != plan "
@@ -234,7 +245,7 @@ def grid_solve(spec: ConvSpec, p: int, hw: HardwareModel, *,
     if best is None:
         raise ValueError(
             f"no emitable zigzag strategy fits size_mem={hw.size_mem} "
-            f"for layer {spec.c_in}x{spec.h_in}x{spec.w_in}"
+            f"for layer {spec.in_channels}x{spec.h_in}x{spec.w_in}"
             f"->{spec.c_out}")
     obj = best.objective(hw)
     return SolveResult(
@@ -297,9 +308,9 @@ def _chain_node(k: int, shape: tuple[int, int, int], spec: ConvSpec) -> Node:
     """How :func:`glue` adapts an (H, W, C) ``shape`` to ``spec``'s
     input, as the node of layer ``k`` reading layer ``k - 1``."""
     h, w, c = shape
-    if c != spec.c_in:
+    if c != spec.in_channels:
         raise KernelShapeError(
-            f"{c} channels feed a layer that expects {spec.c_in}")
+            f"{c} channels feed a layer that expects {spec.in_channels}")
     pool = None
     if h > spec.h_in or w > spec.w_in:
         if h % 2 or w % 2:
@@ -348,10 +359,19 @@ def read_graph(layers: Sequence[dict]
     * ``add``: an earlier layer whose output is added to the conv's; the
       shapes must match.
     * ``relu``: apply ReLU after the add.
+    * ``gelu``: apply the exact (erf) GELU after the add; not with
+      ``relu``.
+    * ``ln``: a LayerNorm over channels (epsilon :data:`LN_EPS`, biased
+      variance, identity affine), ``"in"`` on the tensor the conv reads,
+      after the pool and before the pad, or ``"out"`` on the layer's
+      output, after the add and the activation.
 
     A layer with any of them must get, after pool and pad, exactly its
-    own input shape.  A layer with shape keys alone reads layer k - 1
-    through the chain's glue (:func:`glue`).  Layer 0 reads the
+    own input shape.  Besides the shape keys a layer may hold
+    ``groups``, ``ConvSpec.groups``: 1 (the default) or ``n_kernels``
+    with ``c_in`` 1, a depthwise conv reading ``n_kernels`` channels.
+    A layer with shape keys alone reads layer k - 1 through the chain's
+    glue (:func:`glue`).  Layer 0 reads the
     network's input as it is: its ``c_in, h_in, w_in`` are the input's
     shape.  Any other key is refused."""
     if not layers:
@@ -360,15 +380,24 @@ def read_graph(layers: Sequence[dict]
     nodes: list[Node] = []
     shapes: list[tuple[int, int, int]] = []     # each layer's output
     for k, layer in enumerate(layers):
-        unknown = sorted(set(layer) - set(SHAPE_KEYS) - set(GRAPH_KEYS))
+        unknown = sorted(set(layer) - set(SHAPE_KEYS) - {"groups"}
+                         - set(GRAPH_KEYS))
         if unknown:
             raise GraphError(f"layer {k}: unknown keys {unknown}")
         missing = [key for key in SHAPE_KEYS if key not in layer]
         if missing:
             raise GraphError(f"layer {k}: missing shape keys {missing}")
-        spec = ConvSpec(**{key: layer[key] for key in SHAPE_KEYS})
+        groups = layer.get("groups", 1)
+        if isinstance(groups, bool) or not isinstance(groups, int):
+            raise GraphError(f"layer {k}: groups must be a count, not "
+                             f"{groups!r}")
+        try:
+            spec = ConvSpec(**{key: layer[key] for key in SHAPE_KEYS},
+                            groups=groups)
+        except ValueError as e:
+            raise GraphError(f"layer {k}: {e}") from e
         if k == 0:
-            image = (spec.h_in, spec.w_in, spec.c_in)
+            image = (spec.h_in, spec.w_in, spec.in_channels)
         if not set(layer) & set(GRAPH_KEYS):
             node = (Node(source=-1) if k == 0
                     else _chain_node(k, shapes[-1], spec))
@@ -394,17 +423,26 @@ def _graph_node(k: int, layer: dict, spec: ConvSpec,
         raise GraphError(f"layer {k}: pad must be a count of rows, not "
                          f"{pad!r}")
     relu = layer.get("relu", False)
-    if not isinstance(relu, bool):
-        raise GraphError(f"layer {k}: relu must be true or false")
+    gelu = layer.get("gelu", False)
+    for key, flag in (("relu", relu), ("gelu", gelu)):
+        if not isinstance(flag, bool):
+            raise GraphError(f"layer {k}: {key} must be true or false")
+    if relu and gelu:
+        raise GraphError(f"layer {k}: relu and gelu are two activations; "
+                         f"a layer takes one")
+    ln = layer.get("ln")
+    if ln not in (None, "in", "out"):
+        raise GraphError(f"layer {k}: ln must be \"in\" or \"out\", not "
+                         f"{ln!r}")
     if k == 0 and (source != -1 or pool is not None or pad):
         raise GraphError("layer 0 reads the network's input as it is: "
                          "no other input, pool or pad")
     h, w, c = image if source < 0 else shapes[source]
     if pool is not None:
         h, w = pooled_hw(pool, h, w)
-    if c != spec.c_in:
+    if c != spec.in_channels:
         raise GraphError(f"layer {k}: {c} channels feed a layer that "
-                         f"expects {spec.c_in}")
+                         f"expects {spec.in_channels}")
     if (h + 2 * pad, w + 2 * pad) != (spec.h_in, spec.w_in):
         raise GraphError(
             f"layer {k}: a {h}x{w} map padded by {pad} is not its "
@@ -420,7 +458,7 @@ def _graph_node(k: int, layer: dict, spec: ConvSpec,
             raise GraphError(f"layer {k}: adds layer {add}'s "
                              f"{shapes[add]} to its own {out} output")
     return Node(source=source, pool=pool, pad=((pad, pad), (pad, pad)),
-                add=add, relu=relu)
+                add=add, relu=relu, gelu=gelu, ln=ln)
 
 
 # --------------------------------------------------------------------- #
@@ -438,6 +476,28 @@ def apply_pool(y: jax.Array, kind: str) -> jax.Array:
     if kind == "avg_global":
         return jnp.mean(y, axis=(0, 1), keepdims=True)
     raise GraphError(f"unknown pool {kind!r}")
+
+
+def layer_norm(y: jax.Array) -> jax.Array:
+    """``y`` (H, W, C) normalised over its channels at each pixel: mean 0
+    and biased variance 1 up to :data:`LN_EPS`, identity affine, in f32.
+    Counts ``executor/layernorms``."""
+    REGISTRY.incr("executor/layernorms")
+    with jax.named_scope("executor.layernorm"):
+        y32 = y.astype(jnp.float32)
+        mean = jnp.mean(y32, axis=-1, keepdims=True)
+        var = jnp.mean(jnp.square(y32 - mean), axis=-1, keepdims=True)
+        return ((y32 - mean) * lax.rsqrt(var + LN_EPS)).astype(y.dtype)
+
+
+def gelu(y: jax.Array) -> jax.Array:
+    """The exact GELU, ``y * Phi(y)`` through erf, in f32.  Counts
+    ``executor/gelus``."""
+    REGISTRY.incr("executor/gelus")
+    with jax.named_scope("executor.gelu"):
+        y32 = y.astype(jnp.float32)
+        return (0.5 * y32 * (1.0 + lax.erf(y32 * (2 ** -0.5)))
+                ).astype(y.dtype)
 
 
 def glue(y: jax.Array, spec: ConvSpec) -> jax.Array:
@@ -514,8 +574,6 @@ _EMITTED: dict[int, tuple[weakref.ref, _Program]] = {}
 def _emitted_program(plan: NetworkPlan) -> _Program:
     """``plan``'s emitted layers and graph, emitting them on the first
     call with this plan object."""
-    # Lazy import, as in _execute.
-    from repro.obs.metrics import REGISTRY
     key = id(plan)
     entry = _EMITTED.get(key)
     if entry is not None and entry[0]() is plan:
@@ -540,8 +598,6 @@ def _emitted_program(plan: NetworkPlan) -> _Program:
 def _execute(x, weights, *, program: _Program, interpret: bool
              ) -> jax.Array:
     # Runs only while JAX traces the program, i.e. on a jit-cache miss.
-    # Lazy import: repro.obs imports this module (through kerncheck).
-    from repro.obs.metrics import REGISTRY
     REGISTRY.incr("executor/traces")
     image = jnp.transpose(x, (1, 2, 0))
     outs: list[jax.Array] = []
@@ -560,12 +616,17 @@ def _execute(x, weights, *, program: _Program, interpret: bool
             # before it runs after it, so the k-th conv kernel on the
             # device is layer k.
             h, _ = lax.optimization_barrier((h, outs[-1]))
+        if node.ln == "in":
+            h = layer_norm(h)
         if node.pad != ((0, 0), (0, 0)):
             h = jnp.pad(h, (*node.pad, (0, 0)))
         spec = layer.spec
         REGISTRY.incr("executor/taps", spec.h_k * spec.w_k)
-        REGISTRY.incr("executor/tap_dots", dots_per_step(
-            spec.h_k, spec.w_k, spec.c_in, h.dtype))
+        if spec.groups > 1:
+            REGISTRY.incr("executor/depthwise")
+        else:
+            REGISTRY.incr("executor/tap_dots", dots_per_step(
+                spec.h_k, spec.w_k, spec.c_in, h.dtype))
         h = layer.run_hwc(h, jnp.transpose(w, (2, 3, 1, 0)),
                           interpret=interpret)
         if node.add is not None:
@@ -573,6 +634,10 @@ def _execute(x, weights, *, program: _Program, interpret: bool
             REGISTRY.incr("executor/joins")
         if node.relu:
             h = jnp.maximum(h, 0)
+        if node.gelu:
+            h = gelu(h)
+        if node.ln == "out":
+            h = layer_norm(h)
         outs.append(h)
     return jnp.transpose(outs[-1], (2, 0, 1))
 

@@ -54,7 +54,7 @@ def _footprint_elements(m: MemoryState, spec: ConvSpec,
     """Resident elements of a formal state, with S2 cell weighting
     (mirrors ``analysis.verifier``'s occupancy ledger)."""
     kelem = spec.c_in * spec.h_k * spec.w_k
-    base = m.inp.bit_count() * spec.c_in + m.ker.bit_count() * kelem
+    base = m.inp.bit_count() * spec.in_channels + m.ker.bit_count() * kelem
     if kernel_groups is None:
         return base + m.out.bit_count() * spec.c_out
     g_count = len(kernel_groups)
@@ -81,7 +81,7 @@ def add_plan_layer(tl: Timeline, strategy, spec: ConvSpec,
         m = apply_step(m, s)
         tl.add_counter("vmem_elements", chip, t,
                        _footprint_elements(m, spec, kernel_groups))
-        cum_read += s.i_slice.bit_count() * spec.c_in \
+        cum_read += s.i_slice.bit_count() * spec.in_channels \
             + s.k_sub.bit_count() * spec.c_in * spec.h_k * spec.w_k
         tl.add_counter("dram_read_elements", chip, t, cum_read)
     return t, cum_read

@@ -101,7 +101,7 @@ def decompose_step(step: Step, spec: ConvSpec, hw: HardwareModel,
     n_pix = step.i_slice.bit_count()
     n_ker = step.k_sub.bit_count()
     load_dur = (n_pix + n_ker * kelem) * hw.t_l
-    load_elements = n_pix * spec.c_in + n_ker * kelem
+    load_elements = n_pix * spec.in_channels + n_ker * kelem
 
     if kernel_groups is None:
         wb_units = step.w.bit_count()

@@ -46,8 +46,11 @@ SOLVE_KNOB_DEFAULTS: dict[str, Any] = {
 # --------------------------------------------------------------------- #
 
 def spec_key(spec: ConvSpec) -> list[int]:
-    return [spec.c_in, spec.h_in, spec.w_in, spec.n_kernels,
-            spec.h_k, spec.w_k, spec.s_h, spec.s_w]
+    """The spec's fields in order; ``groups`` only where it is not 1, so
+    a dense spec keeps the key it had before groups existed."""
+    key = [spec.c_in, spec.h_in, spec.w_in, spec.n_kernels,
+           spec.h_k, spec.w_k, spec.s_h, spec.s_w]
+    return key if spec.groups == 1 else key + [spec.groups]
 
 
 def hw_key(hw: HardwareModel) -> dict[str, Any]:

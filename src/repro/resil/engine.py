@@ -377,7 +377,7 @@ def run_faulted(specs: Sequence[ConvSpec], cluster: ClusterModel,
                     kinds.append("vmem_shrink")
             spec = specs[gi]
             rec = _replan(gi, new_cluster, "+".join(kinds),
-                          restage_elems=spec.num_pixels * spec.c_in)
+                          restage_elems=spec.num_pixels * spec.in_channels)
             t += rec.total
             controller.advance(rec.total)
             continue                     # re-read lp from the new plan
@@ -413,7 +413,7 @@ def run_faulted(specs: Sequence[ConvSpec], cluster: ClusterModel,
                 new_cluster = surviving_cluster(cur_cluster)
                 spec = specs[gi]
                 rec = _replan(gi, new_cluster, "chip_death",
-                              restage_elems=spec.num_pixels * spec.c_in)
+                              restage_elems=spec.num_pixels * spec.in_channels)
                 rec.elastic = controller.elastic_plan(survivors)
                 t += rec.total
                 controller.advance(rec.total)

@@ -20,6 +20,9 @@ class ConvLayer:
 
     def __post_init__(self):
         s = self.spec
+        if s.groups != 1:
+            raise NotImplementedError(
+                f"the simulator computes dense convs, not groups={s.groups}")
         if self.input.shape != (s.c_in, s.h_in, s.w_in):
             raise ValueError(f"input shape {self.input.shape} != spec "
                              f"{(s.c_in, s.h_in, s.w_in)}")

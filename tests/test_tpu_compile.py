@@ -2,8 +2,9 @@
 
 Interpret mode cannot see what the chip's compiler refuses (unaligned
 blocks and slices, unsupported relayouts, an input left in VMEM), so
-these tests compile at the real ``resnet8``, ``lenet5`` and ResNet-50
-widths for a described ``v5e:2x2`` topology, with ``interpret=False``.
+these tests compile at the real ``resnet8``, ``lenet5``, ResNet-50 and
+ConvNeXt-T widths for a described ``v5e:2x2`` topology, with
+``interpret=False``.
 Nothing runs.  The topology is described inside a fixture: only one
 process may load the TPU library, and only the worker that runs this
 file does.
@@ -29,8 +30,10 @@ from repro.kernels.emit import (
 
 NETS = ("resnet8", "lenet5")
 DTYPES = ("float32", "bfloat16")
-RESNET50 = json.loads((Path(__file__).resolve().parents[1] / "benchmarks"
-                       / "chip" / "configs" / "resnet50-f32.json").read_text())
+CONFIGS = (Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+           / "configs")
+RESNET50 = json.loads((CONFIGS / "resnet50-f32.json").read_text())
+CONVNEXT = json.loads((CONFIGS / "convnext-t-f32.json").read_text())
 
 
 @functools.cache
@@ -58,11 +61,17 @@ def _resnet50_plan():
                        name="resnet50")
 
 
-def _resnet50_distinct():
-    """Index of the first layer of each distinct ResNet-50 conv shape."""
+@functools.cache
+def _convnext_plan():
+    return plan_layers(CONVNEXT["layers"], HardwareModel(**CONVNEXT["budget"]),
+                       name="convnext-t")
+
+
+def _distinct(cfg=RESNET50):
+    """Index of the first layer of each distinct conv shape of ``cfg``."""
     seen, out = set(), []
-    for k, layer in enumerate(RESNET50["layers"]):
-        key = tuple(layer[s] for s in SHAPE_KEYS)
+    for k, layer in enumerate(cfg["layers"]):
+        key = tuple(layer[s] for s in SHAPE_KEYS) + (layer.get("groups"),)
         if key not in seen:
             seen.add(key)
             out.append(k)
@@ -96,7 +105,7 @@ def _no_compile_cache():
 
 def _compile_layer(one_chip, lp, dtype):
     e, s = emit_layer_kernel(lp), lp.spec
-    x = jax.ShapeDtypeStruct((s.h_in, s.w_in, s.c_in), dtype,
+    x = jax.ShapeDtypeStruct((s.h_in, s.w_in, s.in_channels), dtype,
                              sharding=one_chip)
     w = jax.ShapeDtypeStruct((s.h_k, s.w_k, s.c_in, s.c_out), dtype,
                              sharding=one_chip)
@@ -118,11 +127,26 @@ def test_planned_conv_compiles_for_v5e(one_chip, name, index, dtype):
     _compile_layer(one_chip, _plan(name).layers[index], dtype)
 
 
-@pytest.mark.parametrize("index", _resnet50_distinct())
+@pytest.mark.parametrize("index", _distinct())
 def test_resnet50_conv_compiles_for_v5e(one_chip, index):
     """The strided, 1x1, 7x7, wide and FC kernels: each distinct f32
     conv shape of ResNet-50 v1.5."""
     _compile_layer(one_chip, _resnet50_plan().layers[index], "float32")
+
+
+@pytest.mark.parametrize("index", _distinct(CONVNEXT))
+def test_convnext_conv_compiles_for_v5e(one_chip, index):
+    """The 4x4/4 stem, the 2x2/2 downsamples, the 96-to-3072-channel
+    1x1s, the FC, and the depthwise 7x7s on 96 (lane-padded), 192, 384
+    and 768 channels: each distinct f32 conv shape of ConvNeXt-T.  A
+    depthwise kernel takes its Λ as one (ph, 128) row block a tap."""
+    lp = _convnext_plan().layers[index]
+    call = next(line for line in _compile_layer(one_chip, lp, "float32")
+                .splitlines() if "tpu_custom_call" in line)
+    s = lp.spec
+    if s.groups > 1:
+        ph = -(-s.in_channels // 128)
+        assert f"f32[{s.h_k},{s.w_k},{ph},128]{{" in call
 
 
 @pytest.mark.parametrize("index,c_in,k,dots", [(0, 3, 7, 2), (3, 64, 3, 5)])
@@ -163,6 +187,33 @@ def test_resnet50_network_compiles_in_layer_order_for_v5e(one_chip):
     projection shortcut before the first 1x1 that reads the same
     tensor)."""
     plan = _resnet50_plan()
+    specs = [lp.spec for lp in plan.layers]
+    s0 = specs[0]
+    x = jax.ShapeDtypeStruct((s0.c_in, s0.h_in, s0.w_in), "float32",
+                             sharding=one_chip)
+    ws = [jax.ShapeDtypeStruct((s.c_out, s.c_in, s.h_k, s.w_k), "float32",
+                               sharding=one_chip) for s in specs]
+    compiled = jax.jit(lambda x, ws: execute_network(
+        plan, x, ws, interpret=False)).lower(x, ws).compile()
+    assert compiled.out_info.shape == (1000, 1, 1)
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY"):]
+    kernels = re.findall(r"= f32\[([0-9,]+)\]\{[^}]*\} custom-call\("
+                         r"[^)]*\), custom_call_target=\"tpu_custom_call\"",
+                         entry)
+    expected = []
+    for lp in plan.layers:
+        e, s = emit_layer_kernel(lp), lp.spec
+        expected.append(f"{s.h_out},{s.w_out // e.t_run},{e.t_run},"
+                        f"{s.c_out}")
+    assert kernels == expected
+
+
+def test_convnext_network_compiles_in_layer_order_for_v5e(one_chip):
+    """One program of 59 conv kernels, 18 of them depthwise, and the
+    LayerNorms, GELUs, joins and pool between them, its kernels
+    scheduled in the file's layer order."""
+    plan = _convnext_plan()
     specs = [lp.spec for lp in plan.layers]
     s0 = specs[0]
     x = jax.ShapeDtypeStruct((s0.c_in, s0.h_in, s0.w_in), "float32",
